@@ -37,6 +37,8 @@ def main() -> None:
                    help="comma-separated subset of benchmark names")
     args = p.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

@@ -178,18 +178,21 @@ def make_trace(cmds, banks=None, rows=None, cols=None, data=None, dts=None,
         pass  # traced/abstract inputs cannot be walked -- skip validation
     cmd = jnp.asarray(cmds, dtype=jnp.int32)
     n = cmd.shape[0]
-    z = jnp.zeros(n, dtype=jnp.int32)
-    bank = z if banks is None else jnp.asarray(banks, dtype=jnp.int32)
-    row = z if rows is None else jnp.asarray(rows, dtype=jnp.int32)
-    col = z if cols is None else jnp.asarray(cols, dtype=jnp.int32)
+    # default and broadcast fields are filled in host memory: a transfer,
+    # not a device program per trace length
+    z = np.zeros(n, dtype=np.int32)
+    bank = jnp.asarray(z if banks is None else banks, dtype=jnp.int32)
+    row = jnp.asarray(z if rows is None else rows, dtype=jnp.int32)
+    col = jnp.asarray(z if cols is None else cols, dtype=jnp.int32)
     if data is None:
-        dat = jnp.zeros((n, LINE_WORDS), dtype=jnp.uint32)
-    else:
-        dat = jnp.asarray(data, dtype=jnp.uint32)
-        if dat.ndim == 1:
-            dat = jnp.broadcast_to(dat[None, :], (n, LINE_WORDS))
-    dt = (jnp.full(n, default_dt, dtype=jnp.int32) if dts is None
-          else jnp.asarray(dts, dtype=jnp.int32))
+        data = np.zeros((n, LINE_WORDS), dtype=np.uint32)
+    elif np.ndim(data) == 1:
+        xp = jnp if isinstance(data, jax.Array) else np
+        data = xp.broadcast_to(xp.asarray(data, dtype=xp.uint32)[None, :],
+                               (n, LINE_WORDS))
+    dat = jnp.asarray(data, dtype=jnp.uint32)
+    dt = jnp.asarray(np.full(n, default_dt, dtype=np.int32) if dts is None
+                     else dts, dtype=jnp.int32)
     trace = CommandTrace(cmd, bank, row, col, dat, dt)
     import os
     if os.environ.get("REPRO_TRACE_LINT", "off") != "off":
@@ -200,15 +203,19 @@ def make_trace(cmds, banks=None, rows=None, cols=None, data=None, dts=None,
 
 
 def concat_traces(*traces: CommandTrace) -> CommandTrace:
-    return CommandTrace(*[jnp.concatenate(f) for f in zip(*traces)])
+    """Concatenate concrete traces (in host memory, one transfer per
+    field)."""
+    return CommandTrace(*[jnp.asarray(np.concatenate([np.asarray(x)
+                                                      for x in f]))
+                          for f in zip(*traces)])
 
 
 def tile_trace(trace: CommandTrace, reps: int) -> CommandTrace:
-    """Repeat a command loop ``reps`` times (paper's loop-until-measured)."""
-    return CommandTrace(
-        jnp.tile(trace.cmd, reps), jnp.tile(trace.bank, reps),
-        jnp.tile(trace.row, reps), jnp.tile(trace.col, reps),
-        jnp.tile(trace.data, (reps, 1)), jnp.tile(trace.dt, reps))
+    """Repeat a concrete command loop ``reps`` times (paper's
+    loop-until-measured), in host memory."""
+    return CommandTrace(*[
+        jnp.asarray(np.tile(np.asarray(x), (reps,) + (1,) * (x.ndim - 1)))
+        for x in trace])
 
 
 def pad_trace(trace: CommandTrace, length: int) -> CommandTrace:
@@ -236,6 +243,22 @@ def pad_trace(trace: CommandTrace, length: int) -> CommandTrace:
         jnp.concatenate([trace.dt, zi]))
 
 
+def stack_padded(traces, length: int, rows: int | None = None) -> CommandTrace:
+    """Stack concrete traces into one ``(rows, length)`` batch, each
+    NOP-padded like :func:`pad_trace`, with all-NOP rows after them up to
+    ``rows`` (default: one row per trace).  The padding happens in host
+    memory, so a batch of ragged lengths costs no per-length device
+    program — one transfer per field."""
+    fields = []
+    for parts in zip(*traces):
+        out = np.zeros((rows or len(parts), length) + parts[0].shape[1:],
+                       np.asarray(parts[0]).dtype)     # zeros are NOPs
+        for i, x in enumerate(parts):
+            out[i, :x.shape[0]] = np.asarray(x)
+        fields.append(jnp.asarray(out))
+    return CommandTrace(*fields)
+
+
 def batch_traces(traces_and_skips) -> tuple[CommandTrace, jax.Array]:
     """Stack variable-length traces into one fixed-shape batch.
 
@@ -248,8 +271,7 @@ def batch_traces(traces_and_skips) -> tuple[CommandTrace, jax.Array]:
     """
     pairs = list(traces_and_skips)
     length = max(tr.n for tr, _ in pairs)
-    padded = [pad_trace(tr, length) for tr, _ in pairs]
-    batch = CommandTrace(*[jnp.stack(f) for f in zip(*padded)])
+    batch = stack_padded([tr for tr, _ in pairs], length)
     idx = np.arange(length)
     weight = np.stack([(idx >= skip) & (idx < tr.n)
                        for tr, skip in pairs]).astype(np.float32)

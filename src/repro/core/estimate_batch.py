@@ -45,15 +45,17 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.dram import CommandTrace, N_BANKS, N_ROW_BANDS, batch_traces
+from repro.core.dram import (CommandTrace, N_BANKS, N_ROW_BANDS, batch_traces,
+                             stack_padded)
 from repro.core.energy_model import (EnergyReport, PowerParams, _report,
                                      charge_from_features,
                                      distribution_features,
                                      extract_structural_features,
                                      finalize_features, scale_report,
                                      surface_charge, surface_cycles)
-from repro.core.fleet import batched_pair_totals
+from repro.core.fleet import batched_pair_totals, pad_leading, pad_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,19 +104,11 @@ def bucketed_trace_batch(traces: Sequence[CommandTrace], n_slots: int,
     if longest > length:
         raise ValueError(f"longest trace ({longest} commands) exceeds the "
                          f"length bucket ({length})")
-    from repro.core.dram import pad_trace
-    padded = [pad_trace(tr, length) for tr in traces]
-    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
-    weight = jnp.stack([(jnp.arange(length) < int(tr.n)).astype(jnp.float32)
-                        for tr in traces])
-    pad_rows = n_slots - len(traces)
-    if pad_rows:
-        stacked = jax.tree_util.tree_map(
-            lambda x: jnp.concatenate(
-                [x, jnp.zeros((pad_rows,) + x.shape[1:], x.dtype)]), stacked)
-        weight = jnp.concatenate(
-            [weight, jnp.zeros((pad_rows, length), jnp.float32)])
-    return TraceBatch(stacked, weight)
+    weight = np.zeros((n_slots, length), np.float32)
+    for i, tr in enumerate(traces):
+        weight[i, :int(tr.n)] = 1.0
+    return TraceBatch(stack_padded(traces, length, n_slots),
+                      jnp.asarray(weight))
 
 
 def original_traces(traces, tb: TraceBatch) -> list[CommandTrace]:
@@ -271,17 +265,6 @@ def _surface_cycles_batch(trace: CommandTrace, weight) -> jax.Array:
     return jax.vmap(surface_cycles)(trace, weight)         # (T, 8, R)
 
 
-def _pad_leading(tree, pad: int):
-    """Extend every leaf's leading axis by ``pad`` rows replicating row 0
-    (any valid params work — pad modules are sliced off before the report;
-    replication keeps the chunk numerically well-behaved)."""
-    if pad == 0:
-        return tree
-    return jax.tree_util.tree_map(
-        lambda x: jnp.concatenate(
-            [x, jnp.broadcast_to(x[:1], (pad,) + x.shape[1:])]), tree)
-
-
 def chunked_surface_reports(trace: CommandTrace, weight, stacked: PowerParams,
                             *, module_chunk: int,
                             trace_chunk: int | None = None,
@@ -305,17 +288,14 @@ def chunked_surface_reports(trace: CommandTrace, weight, stacked: PowerParams,
                    else min(int(trace_chunk), n_traces))
 
     m_pad = (-n_modules) % module_chunk
-    stacked = _pad_leading(stacked, m_pad)
-    t_pad = (-n_traces) % trace_chunk
-    if t_pad:
-        # zero-weight pad rows are exact by the TraceBatch contract
-        trace = _pad_leading(trace, t_pad)
-        weight = jnp.concatenate(
-            [weight, jnp.zeros((t_pad,) + weight.shape[1:], weight.dtype)])
+    stacked = pad_leading(stacked, m_pad)
+    cycles = _surface_cycles_batch(trace, weight)
+    trace, weight = pad_rows(trace, weight, trace_chunk)
+    t_padded = trace.cmd.shape[0]
 
-    acc = jnp.zeros((n_traces + t_pad, n_modules + m_pad, N_BANKS,
-                     N_ROW_BANDS), jnp.float32)
-    for ti in range(0, n_traces + t_pad, trace_chunk):
+    acc = jnp.zeros((t_padded, n_modules + m_pad, N_BANKS, N_ROW_BANDS),
+                    jnp.float32)
+    for ti in range(0, t_padded, trace_chunk):
         tr_c = jax.tree_util.tree_map(lambda x: x[ti:ti + trace_chunk],
                                       trace)
         w_c = weight[ti:ti + trace_chunk]
@@ -326,9 +306,6 @@ def chunked_surface_reports(trace: CommandTrace, weight, stacked: PowerParams,
                                            interpret)
             acc = _scatter_chunk(acc, charge, jnp.int32(ti), jnp.int32(mi))
     charge = acc[:n_traces, :n_modules]
-    cycles = _surface_cycles_batch(
-        jax.tree_util.tree_map(lambda x: x[:n_traces], trace),
-        weight[:n_traces])
     return _report(charge, jnp.broadcast_to(cycles[:, None], charge.shape))
 
 

@@ -30,7 +30,8 @@ import numpy as np
 
 from repro.core import device_sim
 from repro.core.dram import CommandTrace, batch_traces
-from repro.core.energy_model import (PowerParams, charge_from_features,
+from repro.core.energy_model import (PowerParams, _report,
+                                     charge_from_features,
                                      extract_structural_features,
                                      finalize_features, masked_totals)
 
@@ -55,6 +56,46 @@ def stack_params(params: Sequence[PowerParams]) -> PowerParams:
     return jax.tree_util.tree_unflatten(treedef, stacked)
 
 
+def pad_leading(tree, pad: int):
+    """Extend every leaf's leading axis by ``pad`` rows replicating row 0
+    (any valid params work — pad modules are sliced off before the report;
+    replication keeps the chunk numerically well-behaved).  Pad TRACE rows
+    must also get zero weight (:func:`pad_rows`)."""
+    if pad == 0:
+        return tree
+    return jax.tree_util.tree_map(
+        lambda x: jnp.concatenate(
+            [x, jnp.broadcast_to(x[:1], (pad,) + x.shape[1:])]), tree)
+
+
+def pad_rows(trace: CommandTrace, weight: jax.Array, multiple: int):
+    """Pad a batch's leading (trace/probe) axis to a multiple of
+    ``multiple`` with zero-weight copies of row 0 — exact by the
+    TraceBatch contract: a zero-weight row draws no charge and no
+    cycles."""
+    pad = (-trace.cmd.shape[0]) % multiple
+    if pad == 0:
+        return trace, weight
+    return (pad_leading(trace, pad),
+            jnp.concatenate([weight, jnp.zeros((pad,) + weight.shape[1:],
+                                               weight.dtype)]))
+
+
+def mesh_split(mesh) -> tuple[int, int]:
+    """The ``(data, model)`` shard counts of a dispatch mesh, or ``(1, 1)``
+    without one.  A multi-device mesh must be a ``(data, model)`` mesh
+    (``launch.mesh.make_local_mesh``): any other axis would leave devices
+    the dispatch never uses, so it raises instead."""
+    if mesh is None:
+        return 1, 1
+    extra = {a: n for a, n in mesh.shape.items()
+             if a not in ("data", "model") and n > 1}
+    if extra:
+        raise ValueError(f"mesh axes {extra} are not (data, model) axes; "
+                         "the fleet dispatch cannot use their devices")
+    return mesh.shape.get("data", 1), mesh.shape.get("model", 1)
+
+
 class FleetStackCache:
     """Memoized, device-resident stacked fleet params — the zero-restack
     dispatch artifact.
@@ -66,8 +107,8 @@ class FleetStackCache:
     fleet identity (the module objects, which own immutable params) plus
     the target mesh, placed device-resident via
     ``model_api.device_resident`` — sharded over the module axis
-    (``NamedSharding`` on the mesh's ``model`` axis) when a dividing
-    multi-device mesh is passed, replicated otherwise — so repeat
+    (``NamedSharding`` on the mesh's ``model`` axis) when the fleet
+    divides a multi-device mesh, replicated otherwise — so repeat
     dispatches neither restack nor re-transfer parameters."""
 
     def __init__(self, maxsize: int = 8):
@@ -221,8 +262,9 @@ def fleet_surface_energy(modules, trace: CommandTrace, weight: jax.Array,
     the dispatch ``shard_map``\\ s the trace axis over ``data`` and the
     module axis over ``model`` — every (trace, module) pair is independent,
     so the sharded result is bitwise identical to the single-device one.
-    Falls back to the plain dispatch when the axes don't divide the mesh
-    (or the mesh is a single device), with identical numerics either way.
+    Axes that do not divide the mesh pad (zero-weight trace rows, module
+    rows replicating module 0) and the pad is sliced off; a one-device
+    mesh takes the plain dispatch.
 
     ``module_chunk`` (optionally ``trace_chunk``) switches to the
     memory-bounded chunked dispatch
@@ -246,15 +288,16 @@ def fleet_surface_energy(modules, trace: CommandTrace, weight: jax.Array,
                           else module_chunk),
             trace_chunk=trace_chunk, impl=impl)
     stacked = fleet_stacked(modules, mesh)
-    n_modules = stacked.i2n.shape[0]
-    if mesh is not None:
-        n_data = mesh.shape.get("data", 1)
-        n_model = mesh.shape.get("model", 1)
-        if (n_data * n_model > 1
-                and trace.cmd.shape[0] % n_data == 0
-                and n_modules % n_model == 0):
-            return _sharded_surface_fn(mesh, impl == "pallas")(
-                trace, weight, stacked)
+    n_data, n_model = mesh_split(mesh)
+    if n_data * n_model > 1:
+        n_traces, n_modules = trace.cmd.shape[0], stacked.i2n.shape[0]
+        trace_p, weight_p = pad_rows(trace, weight, n_data)
+        stacked_p = pad_leading(stacked, (-n_modules) % n_model)
+        charge = _sharded_surface_fn(mesh, impl == "pallas")(
+            trace_p, weight_p, stacked_p)[:n_traces, :n_modules]
+        cycles = estimate_batch._surface_cycles_batch(trace, weight)
+        return _report(charge,
+                       jnp.broadcast_to(cycles[:, None], charge.shape))
     dispatch = (estimate_batch.pallas_batched_surface_reports
                 if impl == "pallas"
                 else estimate_batch.batched_surface_reports)
@@ -263,19 +306,15 @@ def fleet_surface_energy(modules, trace: CommandTrace, weight: jax.Array,
 
 @functools.lru_cache(maxsize=8)
 def _sharded_surface_fn(mesh, pallas: bool):
-    """The jitted shard_map'd surface dispatch for one (mesh, impl) pair:
-    traces over 'data', modules over 'model'.  Memoized so repeat calls on
-    the same mesh reuse the compiled program.
-
-    Only the CHARGE program is shard_map'd — the ``_report`` finalization
-    runs outside it, exactly like the unsharded and chunked dispatches, so
-    all three paths share one finalization program and stay bitwise
-    identical to each other."""
-    from jax.experimental.shard_map import shard_map
+    """The jitted shard_map'd surface CHARGE program for one (mesh, impl)
+    pair: traces over 'data', modules over 'model'.  Memoized so repeat
+    calls on the same mesh reuse the compiled program.  The ``_report``
+    finalization runs outside it, exactly like the unsharded and chunked
+    dispatches, so all three paths share one finalization program and
+    stay bitwise identical to each other."""
     from jax.sharding import PartitionSpec as P
 
     from repro.core import estimate_batch
-    from repro.core.energy_model import _report
     from repro.kernels.common import interpret_default
     interpret = interpret_default() if pallas else False
 
@@ -283,19 +322,10 @@ def _sharded_surface_fn(mesh, pallas: bool):
         return estimate_batch._surface_chunk_charge(
             trace, weight, stacked, pallas, interpret)
 
-    sharded_charge = jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         charge_fn, mesh=mesh,
         in_specs=(P("data"), P("data"), P("model")),
-        out_specs=P("data", "model"),
-        check_rep=False))
-
-    def run(trace, weight, stacked):
-        charge = sharded_charge(trace, weight, stacked)
-        cycles = estimate_batch._surface_cycles_batch(trace, weight)
-        return _report(charge,
-                       jnp.broadcast_to(cycles[:, None], charge.shape))
-
-    return run
+        out_specs=P("data", "model"), check_vma=False))
 
 
 @functools.lru_cache(maxsize=8)
@@ -303,15 +333,13 @@ def _sharded_measure_fn(mesh, pallas: bool):
     """The jitted shard_map'd campaign measurement for one (mesh, impl)
     pair: probes over 'data', modules over 'model' — the (modules, probes)
     current matrix with every axis evaluated where its shard lives."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     measure = (fleet_measure_current_pallas if pallas
                else fleet_measure_current)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         measure, mesh=mesh,
         in_specs=(P("data"), P("data"), P("model")),
-        out_specs=P("model", "data"),
-        check_rep=False))
+        out_specs=P("model", "data"), check_vma=False))
 
 
 def run_probes(modules, points: Sequence[ProbePoint], *,
@@ -337,10 +365,10 @@ def run_probes(modules, points: Sequence[ProbePoint], *,
     The stacked fleet params come from the zero-restack cache
     (:func:`fleet_stacked`) — repeat calls over the same fleet reuse one
     device-resident stacked artifact instead of restacking per call.
-    With a dividing multi-device ``mesh`` the measurement ``shard_map``\\ s
-    probes over ``data`` and modules over ``model`` (bitwise identical to
-    the single-device dispatch — every (module, probe) pair is
-    independent)."""
+    With a multi-device ``mesh`` the measurement ``shard_map``\\ s probes
+    over ``data`` and modules over ``model``, padding either axis up to
+    its shard count (bitwise identical to the single-device dispatch —
+    every (module, probe) pair is independent)."""
     from repro.core import model_api
     impl = model_api.resolve_impl(impl).name
     if engine == "serial":
@@ -359,17 +387,18 @@ def run_probes(modules, points: Sequence[ProbePoint], *,
     if batch is None:
         batch = ProbeBatch.from_points(points)
     stacked = fleet_stacked(modules, mesh)
-    measure = (fleet_measure_current_pallas if impl == "pallas"
-               else fleet_measure_current)
-    if mesh is not None:
-        n_data = mesh.shape.get("data", 1)
-        n_model = mesh.shape.get("model", 1)
-        if (n_data * n_model > 1
-                and batch.trace.cmd.shape[0] % n_data == 0
-                and stacked.i2n.shape[0] % n_model == 0):
-            measure = _sharded_measure_fn(mesh, impl == "pallas")
-    currents = np.asarray(measure(batch.trace, batch.weight, stacked),
-                          dtype=np.float64)
+    n_data, n_model = mesh_split(mesh)
+    if n_data * n_model > 1:
+        n_probes, n_modules = batch.trace.cmd.shape[0], stacked.i2n.shape[0]
+        trace, weight = pad_rows(batch.trace, batch.weight, n_data)
+        currents = _sharded_measure_fn(mesh, impl == "pallas")(
+            trace, weight, pad_leading(stacked, (-n_modules) % n_model)
+        )[:n_modules, :n_probes]
+    else:
+        measure = (fleet_measure_current_pallas if impl == "pallas"
+                   else fleet_measure_current)
+        currents = measure(batch.trace, batch.weight, stacked)
+    currents = np.asarray(currents, dtype=np.float64)
     if noisy:
         currents = currents * device_sim.measurement_noise_factors(
             [m.spec for m in modules], batch.keys)

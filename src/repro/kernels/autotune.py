@@ -239,6 +239,8 @@ def main(argv=None) -> int:  # pragma: no cover - maintenance entry point
     ap.add_argument("--dry-run", action="store_true",
                     help="print winners without rewriting the table")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     shapes = [tuple(int(v) for v in s.split("x"))
               for s in args.shapes.split(",")]
     for family, run_fn in _family_runners().items():

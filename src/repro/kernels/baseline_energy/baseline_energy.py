@@ -5,9 +5,10 @@ estimators (``repro.core.baselines_power``).  Both physics are pure
 per-command formulas over the shared structural facts (open-bank count,
 power-down state) and a per-vendor datasheet IDD row, so one kernel body
 per baseline, gridded over ``(vendors, traces, command blocks)`` exactly
-like the VAMPIRE energy kernel, covers the whole report matrix: per grid
-cell it reads one (1, BLOCK) slab of per-command planes plus this vendor's
-(1, K) IDD row and writes one masked partial charge sum.
+like the VAMPIRE energy kernel (``kernels.common.energy_grid_call``),
+covers the whole report matrix: per grid cell it reads one (8, BLOCK) tile
+of every per-command plane plus this vendor's IDD row from SMEM and writes
+one lane-dense row of masked partial charge sums.
 
 IDD row layout follows ``baselines_power.BASELINE_IDD_KEYS``:
 ``(IDD0, IDD2N, IDD2P1, IDD3N, IDD4R, IDD4W, IDD5B, IDD2P0, IDD3P,
@@ -19,25 +20,26 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.core.baselines_power import act_pair_charge
+from repro.core.baselines_power import BASELINE_IDD_KEYS, act_pair_charge
 from repro.core.dram import TIMING
 from repro.core.energy_model import N_SURFACE_CELLS
-from repro.kernels.common import cdiv, interpret_default, pad_to
+from repro.kernels.common import energy_grid_call, interpret_default
 
 BLOCK_N = 512
 _T = TIMING
 
 # per-command (T, N) planes, in kernel argument order
 PLANES = ("dt", "is_rd", "is_wr", "is_act", "is_ref", "open_banks", "pd", "w")
+_N_IDD = len(BASELINE_IDD_KEYS)
 
 
 def _masked_charge(kind: str, dt, is_rd, is_wr, is_act, is_ref, open_banks,
                    pd, w, any_act, idd):
     """The fused per-command baseline charge body shared by the scalar-sum
-    and the surface-cell kernels.  Returns the masked (B,) charge vector
-    in mA*cycles."""
+    and the surface-cell reductions.  ``any_act`` is the trace's any-ACT
+    flag broadcast over its commands.  Returns the masked charge tile in
+    mA*cycles."""
     idd0, idd2n, idd2p1, idd3n = idd[0], idd[1], idd[2], idd[3]
     idd4r, idd4w, idd5b = idd[4], idd[5], idd[6]
     idd2p0, idd3p, idd6 = idd[7], idd[8], idd[9]
@@ -68,84 +70,29 @@ def _masked_charge(kind: str, dt, is_rd, is_wr, is_act, is_ref, open_banks,
     return charge * w
 
 
-def _make_kernel(kind: str):
-    def kernel(dt_ref, isrd_ref, iswr_ref, isact_ref, isref_ref, open_ref,
-               pd_ref, w_ref, anyact_ref, idd_ref, o_ref):
-        cw = _masked_charge(kind, dt_ref[0], isrd_ref[0], iswr_ref[0],
-                            isact_ref[0], isref_ref[0], open_ref[0],
-                            pd_ref[0], w_ref[0], anyact_ref[0], idd_ref[0])
-        o_ref[0, 0, 0] = jnp.sum(cw)
-    return kernel
-
-
-def _make_surface_kernel(kind: str):
-    def kernel(dt_ref, isrd_ref, iswr_ref, isact_ref, isref_ref, open_ref,
-               pd_ref, w_ref, cell_ref, anyact_ref, idd_ref, o_ref):
-        cw = _masked_charge(kind, dt_ref[0], isrd_ref[0], iswr_ref[0],
-                            isact_ref[0], isref_ref[0], open_ref[0],
-                            pd_ref[0], w_ref[0], anyact_ref[0], idd_ref[0])
-        # (bank, row-band) cell reduction over the one-hot cell plane
-        o_ref[0, 0, 0, :] = jnp.sum(cell_ref[0] * cw[None, :], axis=1)
-    return kernel
-
-
-_KERNELS = {kind: _make_kernel(kind) for kind in ("micron", "drampower")}
-_SURFACE_KERNELS = {kind: _make_surface_kernel(kind)
-                    for kind in ("micron", "drampower")}
+_CHARGE_FNS = {
+    kind: (lambda planes, _, prm, kind=kind:
+           _masked_charge(kind, *planes, [prm(k) for k in range(_N_IDD)]))
+    for kind in ("micron", "drampower")}
 
 
 def baseline_energy_pallas(kind: str, planes: dict, any_act, table,
                            block_n: int = BLOCK_N,
                            interpret: bool | None = None,
-                           cell_t=None,
+                           cells=None,
                            grid_layout: str = "vti") -> jax.Array:
     """(T, V) masked charge matrix of one baseline physics.  ``planes``
     maps :data:`PLANES` to (T, N) f32 arrays; ``any_act`` is (T,) f32;
-    ``table`` is the stacked (V, K) datasheet matrix.  Passing ``cell_t``
-    (the (T, CELLS, N) one-hot structural cell plane) switches to the
-    surface kernel and returns the (T, V, CELLS) charge decomposition.
-    ``grid_layout`` picks the grid-major order (vendor- vs trace-
-    outermost, ``kernels.vampire_energy._grid_maps``) — pure scheduling,
-    identical partial sums either way."""
-    from repro.kernels.vampire_energy.vampire_energy import _grid_maps
+    ``table`` is the stacked (V, K) datasheet matrix.  Passing ``cells``
+    (the (T, N) structural cell index) switches to the surface reduction
+    and returns the (T, V, CELLS) charge decomposition.  ``grid_layout``
+    picks the grid-major order (``kernels.common.grid_maps``) — pure
+    scheduling, identical partial sums either way."""
     if interpret is None:
         interpret = interpret_default()
-    padded = {}
-    for name in PLANES:
-        padded[name], _ = pad_to(planes[name].astype(jnp.float32),
-                                 block_n, axis=1)
-    n_traces, n_pad = padded["dt"].shape
-    n_vendors, n_keys = table.shape
-    grid_n = cdiv(n_pad, block_n)
-    grid, as_map = _grid_maps(grid_layout, n_vendors, n_traces, grid_n)
-
-    spec_2d = pl.BlockSpec((1, block_n), as_map(lambda v, t, i: (t, i)))
-    tail_specs = [pl.BlockSpec((1,), as_map(lambda v, t, i: (t,))),
-                  pl.BlockSpec((1, n_keys), as_map(lambda v, t, i: (v, 0)))]
-    args = [padded[n] for n in PLANES]
-    if cell_t is None:
-        kernel, cell_specs = _KERNELS[kind], []
-        out_spec = pl.BlockSpec((1, 1, 1), as_map(lambda v, t, i: (v, t, i)))
-        out_shape = jax.ShapeDtypeStruct((n_vendors, n_traces, grid_n),
-                                         jnp.float32)
-    else:
-        kernel = _SURFACE_KERNELS[kind]
-        padded_cell, _ = pad_to(cell_t.astype(jnp.float32), block_n, axis=2)
-        args.append(padded_cell)
-        cell_specs = [pl.BlockSpec((1, N_SURFACE_CELLS, block_n),
-                                   as_map(lambda v, t, i: (t, 0, i)))]
-        out_spec = pl.BlockSpec((1, 1, 1, N_SURFACE_CELLS),
-                                as_map(lambda v, t, i: (v, t, i, 0)))
-        out_shape = jax.ShapeDtypeStruct(
-            (n_vendors, n_traces, grid_n, N_SURFACE_CELLS), jnp.float32)
-    partial = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[spec_2d] * len(PLANES) + cell_specs + tail_specs,
-        out_specs=out_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args, any_act.astype(jnp.float32), table.astype(jnp.float32))
-    if cell_t is None:
-        return jnp.sum(partial, axis=2).T                # (T, V)
-    return jnp.sum(partial, axis=2).transpose(1, 0, 2)   # (T, V, CELLS)
+    args = [planes[n].astype(jnp.float32) for n in PLANES]
+    args.append(jnp.broadcast_to(any_act.astype(jnp.float32)[:, None],
+                                 args[0].shape))
+    return energy_grid_call(_CHARGE_FNS[kind], args, table, cells=cells,
+                            n_cells=N_SURFACE_CELLS, block_n=block_n,
+                            interpret=interpret, grid_layout=grid_layout)

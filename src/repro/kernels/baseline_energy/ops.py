@@ -10,45 +10,43 @@ import jax.numpy as jnp
 
 from repro.core.dram import ACT, N_BANKS, N_ROW_BANDS, RD, REF, WR, \
     CommandTrace
-from repro.core.energy_model import (N_SURFACE_CELLS, structural_state,
-                                     surface_cells, surface_cycles)
-from repro.kernels.baseline_energy.baseline_energy import (
-    BLOCK_N, baseline_energy_pallas)
-from repro.kernels.common import interpret_default
+from repro.core.energy_model import (structural_state, surface_cells,
+                                     surface_cycles)
+from repro.kernels.baseline_energy.baseline_energy import \
+    baseline_energy_pallas
+from repro.kernels.common import interpret_default, pad_batch
 
 
 @functools.partial(jax.jit,
                    static_argnames=("kind", "surface", "block_n",
                                     "interpret", "grid_layout"))
-def _charge_matrix(trace: CommandTrace, weight, table, kind: str,
+def _charge_matrix(trace: CommandTrace, weight, tiled: CommandTrace,
+                   w_tiled, table, kind: str,
                    surface: bool, block_n: int, interpret: bool,
                    grid_layout: str):
-    st = jax.vmap(structural_state)(trace)
+    t = trace.cmd.shape[0]
+    st = jax.vmap(structural_state)(tiled)
     planes = {
-        "dt": trace.dt.astype(jnp.float32),
-        "is_rd": (trace.cmd == RD).astype(jnp.float32),
-        "is_wr": (trace.cmd == WR).astype(jnp.float32),
-        "is_act": (trace.cmd == ACT).astype(jnp.float32),
-        "is_ref": (trace.cmd == REF).astype(jnp.float32),
+        "dt": tiled.dt.astype(jnp.float32),
+        "is_rd": (tiled.cmd == RD).astype(jnp.float32),
+        "is_wr": (tiled.cmd == WR).astype(jnp.float32),
+        "is_act": (tiled.cmd == ACT).astype(jnp.float32),
+        "is_ref": (tiled.cmd == REF).astype(jnp.float32),
         "open_banks": jnp.sum(st.open_before.astype(jnp.float32), axis=2),
         "pd": st.bg_state.astype(jnp.float32),
-        "w": weight.astype(jnp.float32),
+        "w": w_tiled.astype(jnp.float32),
     }
-    any_act = jnp.any(trace.cmd == ACT, axis=1).astype(jnp.float32)
+    any_act = jnp.any(tiled.cmd == ACT, axis=1).astype(jnp.float32)
     if surface:
-        t = trace.cmd.shape[0]
-        cells = jax.vmap(surface_cells)(trace)                   # (T, N)
-        cell_t = jax.nn.one_hot(cells, N_SURFACE_CELLS,
-                                dtype=jnp.float32).transpose(0, 2, 1)
         charge = baseline_energy_pallas(kind, planes, any_act, table,
-                                        block_n=block_n,
-                                        interpret=interpret, cell_t=cell_t,
-                                        grid_layout=grid_layout)
+                                        block_n=block_n, interpret=interpret,
+                                        cells=jax.vmap(surface_cells)(tiled),
+                                        grid_layout=grid_layout)[:t]
         return (charge.reshape(t, -1, N_BANKS, N_ROW_BANDS),
                 jax.vmap(surface_cycles)(trace, weight))
     charge = baseline_energy_pallas(kind, planes, any_act, table,
                                     block_n=block_n, interpret=interpret,
-                                    grid_layout=grid_layout)
+                                    grid_layout=grid_layout)[:t]
     cycles = jnp.sum(trace.dt * weight.astype(jnp.int32), axis=1,
                      dtype=jnp.int32)
     return charge, cycles
@@ -74,5 +72,6 @@ def baseline_charge_matrix(trace: CommandTrace, weight, table, kind: str, *,
         block_n = cfg["block_n"] if block_n is None else block_n
         grid_layout = (cfg["layout"] if grid_layout is None
                        else grid_layout)
-    return _charge_matrix(trace, weight, table, kind, surface, block_n,
-                          interpret, grid_layout)
+    tiled, w_tiled = pad_batch(trace, weight, block_n)
+    return _charge_matrix(trace, weight, tiled, w_tiled, table, kind,
+                          surface, block_n, interpret, grid_layout)

@@ -29,68 +29,67 @@ from repro.core.dram import (ACT, LINE_BITS, N_BANKS, N_ROW_BANDS, REF,
 from repro.core.energy_model import (EnergyReport, N_SURFACE_CELLS,
                                      PowerParams, _report, structural_state,
                                      surface_cells, surface_cycles)
-from repro.kernels.common import interpret_default
+from repro.kernels.common import interpret_default, pad_batch
 from repro.kernels.vampire_energy.vampire_energy import (
-    BLOCK_N, batched_energy_pallas, batched_features_pallas,
-    pack_param_blocks)
+    batched_energy_pallas, batched_features_pallas, pack_params)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("surface", "block_n", "interpret",
                                     "grid_layout"))
-def _charge_matrix(trace: CommandTrace, weight, stacked: PowerParams,
+def _charge_matrix(trace: CommandTrace, weight, tiled: CommandTrace,
+                   w_tiled, stacked: PowerParams,
                    ones_frac, toggle_frac, surface: bool, block_n: int,
                    interpret: bool, grid_layout: str):
-    st = jax.vmap(structural_state)(trace)
-    t, n = trace.cmd.shape
+    t = trace.cmd.shape[0]
+    st = jax.vmap(structural_state)(tiled)
     if ones_frac is None:
         # measured-data modes: the fused popcount/toggle feature kernel
         # over the whole batch's data stream, once
         tmask = (st.has_prev & st.is_rw).astype(jnp.float32)
         ones, togg = batched_features_pallas(
-            trace.data.reshape(t * n, -1), st.prev_data.reshape(t * n, -1),
-            tmask.reshape(t * n), block_n=block_n, interpret=interpret)
-        ones, togg = ones.reshape(t, n), togg.reshape(t, n)
+            tiled.data, st.prev_data, tmask, block_n=block_n,
+            interpret=interpret)
     else:
         # no-data-trace mode: expected fractions replace the data features
-        of = jnp.broadcast_to(jnp.asarray(ones_frac, jnp.float32), (t,))
-        tf = jnp.broadcast_to(jnp.asarray(toggle_frac, jnp.float32), (t,))
-        ones = jnp.where(st.is_rw, of[:, None] * LINE_BITS, 0.0)
-        togg = jnp.where(st.is_rw & st.has_prev, tf[:, None] * LINE_BITS, 0.0)
+        def per_trace(frac):
+            frac = jnp.broadcast_to(jnp.asarray(frac, jnp.float32), (t,))
+            return jnp.pad(frac, (0, w_tiled.shape[0] - t))[:, None]
+        ones = jnp.where(st.is_rw, per_trace(ones_frac) * LINE_BITS, 0.0)
+        togg = jnp.where(st.is_rw & st.has_prev,
+                         per_trace(toggle_frac) * LINE_BITS, 0.0)
 
-    bank_oh = jax.nn.one_hot(trace.bank, N_BANKS, dtype=jnp.float32)
     # the per-command structural ACT factor of every vendor: the (bank,
     # row-band) gather happens HERE (vectorized jnp bookkeeping), so the
     # kernel sees a plain (V, T, N) multiply plane
-    cells = jax.vmap(surface_cells)(trace)                       # (T, N)
+    cells = jax.vmap(surface_cells)(tiled)                       # (T, N)
     surf = stacked.act_surface.reshape(-1, N_SURFACE_CELLS)[:, cells]
+    bank_bits = jnp.left_shift(1, jnp.arange(N_BANKS, dtype=jnp.int32))
     feats = {
         "ones": ones, "togg": togg,
         "op": st.op, "mode": st.il_mode,
-        "dt": trace.dt.astype(jnp.float32),
+        "dt": tiled.dt.astype(jnp.float32),
         "is_rw": st.is_rw.astype(jnp.float32),
-        "is_act": (trace.cmd == ACT).astype(jnp.float32),
-        "is_ref": (trace.cmd == REF).astype(jnp.float32),
+        "is_act": (tiled.cmd == ACT).astype(jnp.float32),
+        "is_ref": (tiled.cmd == REF).astype(jnp.float32),
         "pd": st.bg_state.astype(jnp.float32),
         "row_ones": st.row_ones.astype(jnp.float32),
-        "w": weight.astype(jnp.float32),
+        "w": w_tiled.astype(jnp.float32),
+        "bank": tiled.bank.astype(jnp.int32),
+        "open_bits": jnp.sum(jnp.where(st.open_before, bank_bits, 0),
+                             axis=-1, dtype=jnp.int32),
         "surf": surf.astype(jnp.float32),                        # (V, T, N)
-        "bank_t": bank_oh.transpose(0, 2, 1),                    # (T, 8, N)
-        "open_t": st.open_before.astype(jnp.float32).transpose(0, 2, 1),
     }
-    coeffs, scal, bvec = pack_param_blocks(stacked)
+    table = pack_params(stacked)
     if surface:
-        cell_t = jax.nn.one_hot(cells, N_SURFACE_CELLS,
-                                dtype=jnp.float32).transpose(0, 2, 1)
-        charge = batched_energy_pallas(feats, coeffs, scal, bvec,
-                                       block_n=block_n, interpret=interpret,
-                                       cell_t=cell_t,
-                                       grid_layout=grid_layout)
+        charge = batched_energy_pallas(feats, table, block_n=block_n,
+                                       interpret=interpret, cells=cells,
+                                       grid_layout=grid_layout)[:t]
         return (charge.reshape(t, -1, N_BANKS, N_ROW_BANDS),
                 jax.vmap(surface_cycles)(trace, weight))
-    charge = batched_energy_pallas(feats, coeffs, scal, bvec,
-                                   block_n=block_n, interpret=interpret,
-                                   grid_layout=grid_layout)
+    charge = batched_energy_pallas(feats, table, block_n=block_n,
+                                   interpret=interpret,
+                                   grid_layout=grid_layout)[:t]
     cycles = jnp.sum(trace.dt * weight.astype(jnp.int32), axis=1,
                      dtype=jnp.int32)
     return charge, cycles
@@ -123,8 +122,10 @@ def batched_charge_matrix(trace: CommandTrace, weight, stacked: PowerParams,
         block_n = cfg["block_n"] if block_n is None else block_n
         grid_layout = (cfg["layout"] if grid_layout is None
                        else grid_layout)
-    return _charge_matrix(trace, weight, stacked, ones_frac, toggle_frac,
-                          surface, block_n, interpret, grid_layout)
+    tiled, w_tiled = pad_batch(trace, weight, block_n)
+    return _charge_matrix(trace, weight, tiled, w_tiled, stacked, ones_frac,
+                          toggle_frac, surface, block_n, interpret,
+                          grid_layout)
 
 
 def trace_energy_kernel(trace: CommandTrace, pp: PowerParams) -> EnergyReport:

@@ -8,30 +8,31 @@ does:
    kernel**.  Consumes a padded TraceBatch's data stream once: per-line
    popcount and bus-XOR toggle popcount (the O(N x 512 bit) work, fusing
    the ``kernels/popcount`` and ``kernels/toggle`` bodies into one VMEM
-   pass) with validity masking over NOP/dt=0 pad rows.  Runs ONCE per
-   batch; its outputs are shared by every vendor.
+   pass) with validity masking over NOP/dt=0 pad rows.  The stream is
+   word-major ``(16, T, N)``, so each of the 16 words is an ``(8, block_n)``
+   tile and the per-line sum is 16 elementwise adds.  Runs ONCE per batch;
+   its outputs are shared by every vendor.
 
 2. :func:`batched_energy_pallas` — the **per-vendor fused current/energy
-   kernel**, gridded over ``(vendors, traces, command blocks)``.  For each
-   vendor it fuses the (interleave-mode, op) coefficient select of paper
-   Eq. 2 (masked sum — no per-lane gathers on the VPU), the structural
-   bank factor and open-bank background (8-wide masked reductions over
-   transposed (8, N) layouts, keeping the command axis on the VREG lanes),
-   the I/O-driver term, the bank-state background integrator with burst
-   crediting, ACT/REF charges with the per-(bank, row-band) structural
-   surface factor (gathered into a per-command plane by the assembler — a
-   VMEM multiply here, not a kernel gather), the optional ``ones_quad``
-   curvature (so the *true* simulator params ride the same kernel during
-   characterization), and the pad-row weight mask — one partial charge sum
-   per grid cell, reduced to the (traces, vendors) matrix outside.
+   kernel**, gridded over ``(vendors, trace blocks, command blocks)`` by
+   ``kernels.common.energy_grid_call``.  For each vendor it fuses the
+   (interleave-mode, op) coefficient select of paper Eq. 2 (masked sum —
+   no per-lane gathers on the VPU), the structural bank factor and
+   open-bank background (8-way selects over the bank index and the packed
+   open-bank bits), the I/O-driver term, the bank-state background
+   integrator with burst crediting, ACT/REF charges with the per-(bank,
+   row-band) structural surface factor (gathered into a per-command plane
+   by the assembler — a VMEM multiply here, not a kernel gather), the
+   optional ``ones_quad`` curvature (so the *true* simulator params ride
+   the same kernel during characterization), and the pad-row weight mask.
+   The vendor's scalars come from SMEM; each grid cell writes one
+   lane-dense (8, 128) row of partial sums, reduced to the (traces,
+   vendors) matrix outside.
 
-   Passing ``cell_t`` (the one-hot structural cell plane) switches the
-   same launch to the ``mode='surface'`` kernel variant: the identical
-   fused charge body, but instead of one scalar sum per grid cell it
-   reduces against the (surface-cells, N) plane (the same
-   transposed-layout trick as the bank reductions, 64 lanes wide) and
-   writes one partial charge row per structural cell -> the
-   (traces, vendors, banks, row_bands) surface.
+   Passing ``cells`` (the int32 structural cell index of every command)
+   switches the same launch to the ``mode='surface'`` reduction: the
+   identical fused charge body, with each cell's partial sum written to
+   its own lane -> the (traces, vendors, banks, row_bands) surface.
 
 The index bookkeeping that decides bank state / interleave mode / previous
 line (``energy_model.structural_state``) stays in vectorized jnp: it is
@@ -43,99 +44,125 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.dram import TIMING
+from repro.core.dram import N_BANKS, TIMING
 from repro.core.energy_model import N_SURFACE_CELLS
-from repro.kernels.common import cdiv, interpret_default, pad_to
+from repro.kernels.common import (TRACE_BLOCK, energy_grid_call,
+                                  interpret_default, pad_to)
 from repro.kernels.popcount.popcount import _popcount_u32
 
 BLOCK_N = 512
 LINE_BITS = 512.0
 _T_BURST = float(TIMING.tBURST)
 
-# layout of the packed per-vendor scalar row (see pack_param_blocks);
-# the low-power LUT entries are appended at the END so the first eight
-# slots keep their historical positions
+# layout of the packed per-vendor scalar row (see pack_params): the
+# (mode, op, coeff) Eq. 2 table, the scalar leaves in ``_SCAL_FIELDS``
+# order, then the per-bank open delta, read factor and write factor
 _SCAL_FIELDS = ("i2n", "q_actpre", "row_ones_slope", "q_ref", "i_pd",
                 "io_read_ma_per_one", "io_write_ma_per_zero", "ones_quad",
                 "i_pd_slow", "i_actpd", "i_sr")
+_COEFF0 = 0
+_SCAL0 = _COEFF0 + 4 * 2 * 3
+_OPEN0 = _SCAL0 + len(_SCAL_FIELDS)
+_RD0 = _OPEN0 + N_BANKS
+_WR0 = _RD0 + N_BANKS
 
 
-def pack_param_blocks(stacked):
-    """Pack a stacked (leading vendor axis) ``PowerParams`` into the three
-    fixed-shape blocks the energy kernel tiles over the vendor grid axis:
-    ``coeffs (V,4,2,3)``, ``scal (V,11)`` (order ``_SCAL_FIELDS``), and
-    ``bvec (V,3,8)`` (open-bank delta, read factor, write factor)."""
-    coeffs = stacked.datadep.astype(jnp.float32)
-    scal = jnp.stack([getattr(stacked, f).astype(jnp.float32)
-                      for f in _SCAL_FIELDS], axis=-1)
-    bvec = jnp.stack([stacked.bank_open_delta.astype(jnp.float32),
-                      stacked.bank_read_factor.astype(jnp.float32),
-                      stacked.bank_write_factor.astype(jnp.float32)], axis=1)
-    return coeffs, scal, bvec
+def pack_params(stacked):
+    """Pack a stacked (leading vendor axis) ``PowerParams`` into the one
+    (V, 59) f32 scalar table the energy kernel reads from SMEM (layout:
+    the ``_COEFF0``/``_SCAL0``/``_OPEN0``/``_RD0``/``_WR0`` offsets)."""
+    n = stacked.i2n.shape[0]
+    cols = [stacked.datadep.reshape(n, -1)]
+    cols += [getattr(stacked, f).reshape(n, 1) for f in _SCAL_FIELDS]
+    cols += [stacked.bank_open_delta, stacked.bank_read_factor,
+             stacked.bank_write_factor]
+    return jnp.concatenate([c.astype(jnp.float32) for c in cols], axis=1)
 
 
 # ---------------------------------------------------------------------------
 # 1. param-independent feature kernel
 # ---------------------------------------------------------------------------
 def _features_kernel(data_ref, prev_ref, tmask_ref, ones_ref, togg_ref):
-    data = data_ref[...]                              # (B, 16) uint32
-    prev = prev_ref[...]
-    ones = jnp.sum(_popcount_u32(data), axis=1).astype(jnp.float32)
-    togg = jnp.sum(_popcount_u32(jnp.bitwise_xor(data, prev)),
-                   axis=1).astype(jnp.float32)
-    ones_ref[...] = ones
-    togg_ref[...] = togg * tmask_ref[...]             # mask pad/first-access
+    ones = togg = jnp.zeros(ones_ref.shape, jnp.int32)
+    for w in range(data_ref.shape[0]):               # 16 words per line
+        data = data_ref[w]                            # (8, B) uint32
+        ones = ones + _popcount_u32(data)
+        togg = togg + _popcount_u32(jnp.bitwise_xor(data, prev_ref[w]))
+    ones_ref[...] = ones.astype(jnp.float32)
+    togg_ref[...] = togg.astype(jnp.float32) * tmask_ref[...]
 
 
 def batched_features_pallas(data, prev, tmask, block_n: int = BLOCK_N,
                             interpret: bool | None = None):
-    """(M, 16) u32 data/prev + (M,) f32 toggle-validity mask ->
-    ((M,) ones, (M,) toggles) as f32, in one fused pass."""
+    """(T, N, 16) u32 data/prev lines + (T, N) f32 toggle-validity mask
+    -> ((T, N) ones, (T, N) toggles) as f32, in one fused pass."""
     if interpret is None:
         interpret = interpret_default()
-    data, m = pad_to(data.astype(jnp.uint32), block_n, axis=0)
-    prev, _ = pad_to(prev.astype(jnp.uint32), block_n, axis=0)
-    tmask, _ = pad_to(tmask.astype(jnp.float32), block_n, axis=0)
-    grid = (cdiv(data.shape[0], block_n),)
+    n_traces, n_cmds = tmask.shape
+
+    def tile(x, t_axis):
+        x, _ = pad_to(x, TRACE_BLOCK, axis=t_axis)
+        return pad_to(x, block_n, axis=t_axis + 1)[0]
+
+    # word-major: every word of the line is its own (T, N) plane
+    data = tile(jnp.moveaxis(data.astype(jnp.uint32), -1, 0), 1)
+    prev = tile(jnp.moveaxis(prev.astype(jnp.uint32), -1, 0), 1)
+    tmask = tile(tmask.astype(jnp.float32), 0)
+    words, t_pad, n_pad = data.shape
+    spec_w = pl.BlockSpec((words, TRACE_BLOCK, block_n),
+                          lambda t, i: (0, t, i))
+    spec_t = pl.BlockSpec((TRACE_BLOCK, block_n), lambda t, i: (t, i))
+    plane = jax.ShapeDtypeStruct((t_pad, n_pad), jnp.float32)
     ones, togg = pl.pallas_call(
         _features_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_n, 16), lambda i: (i, 0)),
-                  pl.BlockSpec((block_n, 16), lambda i: (i, 0)),
-                  pl.BlockSpec((block_n,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((block_n,), lambda i: (i,)),
-                   pl.BlockSpec((block_n,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((data.shape[0],), jnp.float32),
-                   jax.ShapeDtypeStruct((data.shape[0],), jnp.float32)],
+        grid=(t_pad // TRACE_BLOCK, n_pad // block_n),
+        in_specs=[spec_w, spec_w, spec_t],
+        out_specs=[spec_t, spec_t],
+        out_shape=[plane, plane],
         interpret=interpret,
     )(data, prev, tmask)
-    return ones[:m], togg[:m]
+    return ones[:n_traces, :n_cmds], togg[:n_traces, :n_cmds]
 
 
 # ---------------------------------------------------------------------------
 # 2. per-vendor fused current/energy kernel
 # ---------------------------------------------------------------------------
-# feature-plane order shared by the kernel signature and the ops wrapper
+# (T, N) feature-plane order shared by the kernel body and the ops wrapper;
+# ``bank`` (index) and ``open_bits`` (bit b set while bank b is open) are
+# int32, the rest f32
 FEATURE_PLANES = ("ones", "togg", "op", "mode", "dt", "is_rw", "is_act",
-                  "is_ref", "pd", "row_ones", "w")
+                  "is_ref", "pd", "row_ones", "w", "bank", "open_bits")
 
 
-def _masked_charge(ones, togg, op, mode, dt, is_rw, is_act, is_ref, pd,
-                   row_ones, w, surf, bank_t, open_t, coeffs, scal, bvec):
-    """The fused per-command charge body shared by the scalar-sum and the
-    surface-cell kernels.  All per-command args are (B,) f32 except
-    ``bank_t``/``open_t`` (8, B); ``surf`` is this vendor's per-command
-    structural ACT factor (gathered by the assembler).  Returns the masked
-    (B,) charge vector in mA*cycles."""
-    i2n, q_actpre, slope, q_ref_chg = scal[0], scal[1], scal[2], scal[3]
-    i_pd, io_r, io_w, ones_quad = scal[4], scal[5], scal[6], scal[7]
-    i_pd_slow, i_actpd, i_sr = scal[8], scal[9], scal[10]
+def _masked_charge(planes, vendor_planes, prm):
+    """The fused per-command charge body of the scalar-sum and the
+    surface-cell reductions.  ``planes`` are this block's
+    :data:`FEATURE_PLANES` tiles, ``vendor_planes`` is ``(surf,)``, this
+    vendor's per-command structural ACT factor (gathered by the
+    assembler), and ``prm(k)`` reads the vendor's packed scalar ``k``.
+    Returns the masked charge tile in mA*cycles."""
+    (ones, togg, op, mode, dt, is_rw, is_act, is_ref, pd, row_ones, w,
+     bank, open_bits) = planes
+    (surf,) = vendor_planes
+    i2n, q_actpre, slope, q_ref_chg, i_pd, io_r, io_w, ones_quad, \
+        i_pd_slow, i_actpd, i_sr = (prm(_SCAL0 + k)
+                                    for k in range(len(_SCAL_FIELDS)))
+
+    # the per-bank structural terms: open-bank background delta summed
+    # over the open bits, read/write factor selected by the bank index
+    bg_delta = jnp.zeros_like(ones)
+    rd_fac = jnp.zeros_like(ones)
+    wr_fac = jnp.zeros_like(ones)
+    for b in range(N_BANKS):
+        is_open = ((open_bits >> b) & 1) == 1
+        bg_delta = bg_delta + jnp.where(is_open, prm(_OPEN0 + b), 0.0)
+        rd_fac = jnp.where(bank == b, prm(_RD0 + b), rd_fac)
+        wr_fac = jnp.where(bank == b, prm(_WR0 + b), wr_fac)
 
     # background current from the bank state and the background-state code
     # carried in the ``pd`` plane (energy_model.BG_*: 0 active, 1 fast PDN,
     # 2 slow PDN, 3 active PDN, 4 self-refresh) — the kernel twin of
     # ``energy_model.background_current``
-    bg_delta = jnp.sum(open_t * bvec[0][:, None], axis=0)        # (B,)
     i_low = jnp.where(pd == 1.0, i_pd,
                       jnp.where(pd == 2.0, i_pd_slow,
                                 jnp.where(pd == 3.0, i_actpd, i_sr)))
@@ -146,12 +173,11 @@ def _masked_charge(ones, togg, op, mode, dt, is_rw, is_act, is_ref, pd,
     for m in range(4):
         for o in range(2):
             sel = ((mode == m) & (op == o)).astype(jnp.float32)
-            c = coeffs[m, o]
-            base = c[0] + c[1] * ones + c[2] * togg
-            base = base + ones_quad * c[1] * ones * (ones / LINE_BITS - 0.5)
+            k = _COEFF0 + (m * 2 + o) * 3
+            c0, c1, c2 = prm(k), prm(k + 1), prm(k + 2)
+            base = c0 + c1 * ones + c2 * togg
+            base = base + ones_quad * c1 * ones * (ones / LINE_BITS - 0.5)
             cur = cur + sel * base
-    rd_fac = jnp.sum(bank_t * bvec[1][:, None], axis=0)
-    wr_fac = jnp.sum(bank_t * bvec[2][:, None], axis=0)
     io_cur = jnp.where(op == 0, io_r * ones, io_w * (LINE_BITS - ones))
     i_rw = cur * jnp.where(op == 0, rd_fac, wr_fac) + io_cur
 
@@ -164,125 +190,24 @@ def _masked_charge(ones, togg, op, mode, dt, is_rw, is_act, is_ref, pd,
     return charge * w
 
 
-def _energy_kernel(ones_ref, togg_ref, op_ref, mode_ref, dt_ref, isrw_ref,
-                   isact_ref, isref_ref, pd_ref, rowones_ref, w_ref,
-                   surf_ref, bank_t_ref, open_t_ref, coeff_ref, scal_ref,
-                   bvec_ref, o_ref):
-    cw = _masked_charge(
-        ones_ref[0], togg_ref[0], op_ref[0], mode_ref[0], dt_ref[0],
-        isrw_ref[0], isact_ref[0], isref_ref[0], pd_ref[0], rowones_ref[0],
-        w_ref[0], surf_ref[0, 0], bank_t_ref[0], open_t_ref[0],
-        coeff_ref[0], scal_ref[0], bvec_ref[0])
-    o_ref[0, 0, 0] = jnp.sum(cw)
-
-
-def _surface_kernel(ones_ref, togg_ref, op_ref, mode_ref, dt_ref, isrw_ref,
-                    isact_ref, isref_ref, pd_ref, rowones_ref, w_ref,
-                    surf_ref, cell_ref, bank_t_ref, open_t_ref, coeff_ref,
-                    scal_ref, bvec_ref, o_ref):
-    cw = _masked_charge(
-        ones_ref[0], togg_ref[0], op_ref[0], mode_ref[0], dt_ref[0],
-        isrw_ref[0], isact_ref[0], isref_ref[0], pd_ref[0], rowones_ref[0],
-        w_ref[0], surf_ref[0, 0], bank_t_ref[0], open_t_ref[0],
-        coeff_ref[0], scal_ref[0], bvec_ref[0])
-    # cell one-hot reduction (the 8-wide bank trick, CELLS lanes wide):
-    # one partial charge per (bank, row-band) cell of this block
-    o_ref[0, 0, 0, :] = jnp.sum(cell_ref[0] * cw[None, :], axis=1)
-
-
-def _grid_maps(grid_layout: str, n_vendors: int, n_traces: int,
-               grid_n: int):
-    """The grid tuple plus an index-map builder for one grid-major order.
-
-    ``'vti'`` (the historical order) iterates vendors outermost, keeping
-    one trace's feature planes resident across the vendor sweep of a
-    block; ``'tvi'`` iterates traces outermost, keeping one vendor's
-    parameter blocks resident instead.  The autotuner
-    (``kernels/autotune``) picks per (backend, shape-bucket).  ``as_map``
-    lifts a ``(v, t, i) -> block index`` function into the grid's own
-    coordinate order, so the kernels and BlockSpecs stay layout-agnostic.
-    """
-    if grid_layout == "tvi":
-        grid = (n_traces, n_vendors, grid_n)
-
-        def as_map(sel):
-            return lambda t, v, i: sel(v, t, i)
-    elif grid_layout == "vti":
-        grid = (n_vendors, n_traces, grid_n)
-
-        def as_map(sel):
-            return lambda v, t, i: sel(v, t, i)
-    else:
-        raise ValueError(f"unknown grid_layout {grid_layout!r}")
-    return grid, as_map
-
-
-def batched_energy_pallas(feats: dict, coeffs, scal, bvec,
-                          block_n: int = BLOCK_N,
-                          interpret: bool | None = None,
-                          cell_t=None,
+def batched_energy_pallas(feats: dict, table, block_n: int = BLOCK_N,
+                          interpret: bool | None = None, cells=None,
                           grid_layout: str = "vti") -> jax.Array:
-    """The (vendors, traces, blocks)-gridded charge reduction.
+    """The (vendors, trace blocks, command blocks)-gridded charge
+    reduction.
 
     ``feats`` maps :data:`FEATURE_PLANES` names to (T, N) arrays, plus
-    ``surf`` as the (V, T, N) per-command structural ACT factor and
-    ``bank_t``/``open_t`` as (T, 8, N) transposed layouts so the 8-wide
-    reductions keep the command axis on the VREG lanes.  Returns the
-    (T, V) masked charge matrix in mA*cycles — or, when ``cell_t`` (the
-    (T, CELLS, N) one-hot structural cell plane) is passed, switches the
-    grid to the surface kernel and returns the (T, V, CELLS) charge
+    ``surf`` as the (V, T, N) per-command structural ACT factor; ``table``
+    is :func:`pack_params`' (V, 59) scalar table.  Returns the (T, V)
+    masked charge matrix in mA*cycles — or, when ``cells`` (the (T, N)
+    structural cell index) is passed, the (T, V, CELLS) charge
     decomposition of ``mode='surface'``.  ``grid_layout`` picks the
-    grid-major order (see :func:`_grid_maps`) — pure scheduling, the
+    grid-major order (``kernels.common.grid_maps``) — pure scheduling, the
     partial sums are identical either way."""
     if interpret is None:
         interpret = interpret_default()
-    padded = {}
-    for name in FEATURE_PLANES:
-        padded[name], _ = pad_to(feats[name], block_n, axis=1)
-    padded["surf"], _ = pad_to(feats["surf"], block_n, axis=2)
-    for name in ("bank_t", "open_t"):
-        padded[name], _ = pad_to(feats[name], block_n, axis=2)
-    n_traces, n_pad = padded["ones"].shape
-    n_vendors = coeffs.shape[0]
-    grid_n = cdiv(n_pad, block_n)
-    grid, as_map = _grid_maps(grid_layout, n_vendors, n_traces, grid_n)
-
-    spec_2d = pl.BlockSpec((1, block_n), as_map(lambda v, t, i: (t, i)))
-    spec_surf = pl.BlockSpec((1, 1, block_n),
-                             as_map(lambda v, t, i: (v, t, i)))
-    spec_8 = pl.BlockSpec((1, 8, block_n), as_map(lambda v, t, i: (t, 0, i)))
-    param_specs = [pl.BlockSpec((1, 4, 2, 3),
-                                as_map(lambda v, t, i: (v, 0, 0, 0))),
-                   pl.BlockSpec((1, len(_SCAL_FIELDS)),
-                                as_map(lambda v, t, i: (v, 0))),
-                   pl.BlockSpec((1, 3, 8),
-                                as_map(lambda v, t, i: (v, 0, 0)))]
-    args = [padded[n] for n in FEATURE_PLANES] + [padded["surf"]]
-    if cell_t is None:
-        kernel, cell_specs = _energy_kernel, []
-        out_spec = pl.BlockSpec((1, 1, 1), as_map(lambda v, t, i: (v, t, i)))
-        out_shape = jax.ShapeDtypeStruct((n_vendors, n_traces, grid_n),
-                                         jnp.float32)
-    else:
-        kernel = _surface_kernel
-        padded_cell, _ = pad_to(cell_t, block_n, axis=2)
-        args.append(padded_cell)
-        cell_specs = [pl.BlockSpec((1, N_SURFACE_CELLS, block_n),
-                                   as_map(lambda v, t, i: (t, 0, i)))]
-        out_spec = pl.BlockSpec((1, 1, 1, N_SURFACE_CELLS),
-                                as_map(lambda v, t, i: (v, t, i, 0)))
-        out_shape = jax.ShapeDtypeStruct(
-            (n_vendors, n_traces, grid_n, N_SURFACE_CELLS), jnp.float32)
-    args += [padded["bank_t"], padded["open_t"], coeffs, scal, bvec]
-    partial = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=([spec_2d] * len(FEATURE_PLANES) + [spec_surf]
-                  + cell_specs + [spec_8, spec_8] + param_specs),
-        out_specs=out_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    if cell_t is None:
-        return jnp.sum(partial, axis=2).T                # (T, V)
-    return jnp.sum(partial, axis=2).transpose(1, 0, 2)   # (T, V, CELLS)
+    planes = [feats[n] for n in FEATURE_PLANES]
+    return energy_grid_call(_masked_charge, planes, table,
+                            vendor_planes=[feats["surf"]], cells=cells,
+                            n_cells=N_SURFACE_CELLS, block_n=block_n,
+                            interpret=interpret, grid_layout=grid_layout)

@@ -9,14 +9,9 @@ import jax
 
 
 def _mesh(shape, axes):
-    """jax.make_mesh across JAX versions: ``jax.sharding.AxisType`` (and the
-    ``axis_types=`` kwarg) only exist on newer JAX; fall back to the plain
-    call on 0.4.x, where every axis is implicitly auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis auto-sharded."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -27,6 +27,12 @@ instead of the quick reference fit.
     python -m repro.launch.serve --arch qwen2.5-3b --smoke --batch 4 \
         --prompt-len 64 --decode-tokens 32 --data 1 --model 1 \
         --temperature 0.7 --power-report --power-model vampire
+
+``--no-smoke`` selects the architecture's published widths (random
+weights from ``--seed``; nothing is downloaded):
+
+    python -m repro.launch.serve --arch qwen2.5-3b --no-smoke \
+        --decode-tokens 8 --power-report --power-impl pallas
 """
 from __future__ import annotations
 
@@ -288,7 +294,10 @@ def power_report(job: ServeJob, compiled_decode, logits, tokens, *,
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="qwen2.5-3b")
-    p.add_argument("--smoke", action="store_true", default=True)
+    p.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                   default=True,
+                   help="reduced smoke widths (default); --no-smoke runs "
+                        "the published widths")
     p.add_argument("--batch", type=int, default=4)
     p.add_argument("--prompt-len", type=int, default=64)
     p.add_argument("--decode-tokens", type=int, default=32)
@@ -312,6 +321,8 @@ def main():
                    help="saved model blob (model.save: v2 .npz, or legacy "
                         "v1 pickle); quick reference fit when omitted")
     args = p.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     res = run(ServeJob(arch=args.arch, smoke=args.smoke, batch=args.batch,
                        prompt_len=args.prompt_len,
                        decode_tokens=args.decode_tokens,

@@ -511,7 +511,6 @@ def moe_apply_shardmap(params, x, cfg: ModelConfig, mesh, dp_axes=None,
     expert) — standard practice. Under FSDP the expert weights arrive
     data-sharded and are all-gathered per layer (the FSDP contract).
     """
-    from jax.experimental.shard_map import shard_map
     e = cfg.moe
     b, s, d = x.shape
 
@@ -581,12 +580,8 @@ def moe_apply_shardmap(params, x, cfg: ModelConfig, mesh, dp_axes=None,
         y = jax.lax.psum(y, ep_axis)
         return y.reshape(bl, sl, d)
 
-    try:
-        sm = shard_map(local, mesh=mesh, in_specs=(especs, xspec),
+    sm = jax.shard_map(local, mesh=mesh, in_specs=(especs, xspec),
                        out_specs=xspec, check_vma=False)
-    except TypeError:  # older jax: check_rep
-        sm = shard_map(local, mesh=mesh, in_specs=(especs, xspec),
-                       out_specs=xspec, check_rep=False)
     return sm(params, x)
 
 

@@ -18,9 +18,10 @@ parity suite asserts.  The vendor/module-axis half of the mesh story
 lives in ``fleet.fleet_surface_energy(mesh=)``, where the module axis is
 the dispatch's vendor axis and shards over ``'model'``.
 
-Graceful degradation: a 1-device mesh (or no mesh) skips ``shard_map``
-entirely, and a batch whose trace count does not divide the device count
-falls back to the plain jitted dispatch — same numerics on every path.
+A 1-device mesh (or no mesh) skips ``shard_map`` entirely.  On a
+multi-device mesh every dispatch is sharded: a batch whose trace count
+does not divide the device count pads with zero-weight rows (exact by the
+TraceBatch contract) that are sliced off the result.
 
 The compiled-program cache is keyed on (vendors, mode/impl are fixed per
 engine, sharded-or-not); with ring bucketing bounding the batch shapes,
@@ -35,6 +36,7 @@ import jax
 
 from repro.core import model_api
 from repro.core.estimate_batch import TraceBatch
+from repro.core.fleet import pad_rows
 
 
 class ServingEngine:
@@ -67,13 +69,16 @@ class ServingEngine:
     def dispatch(self, tb: TraceBatch, vendors=None):
         """Score one bucket-shaped batch -> the model's report (leaves
         (traces, vendors)-shaped; mode='range' a (lo, mean, hi) triple).
-        Shards the trace axis when the mesh has >1 device and the batch
-        divides it; identical numerics either way."""
+        Shards the trace axis when the mesh has >1 device, padding it to
+        a multiple of the device count; identical numerics either way."""
         vendors = (tuple(int(v) for v in vendors)
                    if vendors is not None else None)
-        sharded = self.n_shards > 1 and tb.n_traces % self.n_shards == 0
-        return self._dispatch_fn(vendors, sharded)(
-            self.resident, tb.trace, tb.weight)
+        if self.n_shards == 1:
+            return self._dispatch_fn(vendors, False)(
+                self.resident, tb.trace, tb.weight)
+        trace, weight = pad_rows(tb.trace, tb.weight, self.n_shards)
+        out = self._dispatch_fn(vendors, True)(self.resident, trace, weight)
+        return jax.tree_util.tree_map(lambda x: x[:tb.n_traces], out)
 
     def _dispatch_fn(self, vendors, sharded: bool):
         # The model rides as a traced ARGUMENT, not a closure: the jit
@@ -89,12 +94,11 @@ class ServingEngine:
                     toggle_frac=self.toggle_frac)
 
             if sharded:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
                 spec = P(tuple(self.mesh.axis_names))
-                call = shard_map(call, mesh=self.mesh,
-                                 in_specs=(P(), spec, spec), out_specs=spec,
-                                 check_rep=False)
+                call = jax.shard_map(call, mesh=self.mesh,
+                                     in_specs=(P(), spec, spec),
+                                     out_specs=spec, check_vma=False)
             fn = jax.jit(call)
             self._fns[(vendors, sharded)] = fn
         return fn

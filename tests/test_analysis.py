@@ -115,7 +115,7 @@ def test_diagnostic_carries_margin_and_message():
 
 
 # ---------------------------------------------------------------------------
-# Property tests (vendored hypothesis): seeded edits and engine parity
+# Property tests (hypothesis): seeded edits and engine parity
 # ---------------------------------------------------------------------------
 @settings(max_examples=20)
 @given(gap=st.integers(min_value=1, max_value=T.tRP - 1))

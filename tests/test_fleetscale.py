@@ -246,6 +246,55 @@ def test_sharded_fleet_surface_bitwise_with_synth_fleet():
                                       err_msg=f"leaf {f}")
 
 
+@pytest.mark.parametrize("shape,split", (
+    (None, (1, 1)),
+    ({"data": 2, "model": 1}, (2, 1)),
+    ({"data": 1, "model": 4, "pipe": 1}, (1, 4)),
+    ({"data": 1, "model": 2, "pipe": 2}, ValueError),
+))
+def test_mesh_split_uses_every_device_or_raises(shape, split):
+    """A multi-device axis the fleet dispatch does not shard over would
+    leave its devices idle: that raises instead of dispatching."""
+    from types import SimpleNamespace
+    mesh = None if shape is None else SimpleNamespace(shape=shape)
+    if split is ValueError:
+        with pytest.raises(ValueError, match="not \\(data, model\\)"):
+            fleet.mesh_split(mesh)
+    else:
+        assert fleet.mesh_split(mesh) == split
+
+
+def test_pad_rows_appends_zero_weight_copies():
+    trace, weight = _surface_batch()
+    n = trace.cmd.shape[0]
+    trace_p, weight_p = fleet.pad_rows(trace, weight, 3)
+    assert trace_p.cmd.shape[0] == weight_p.shape[0] == 3 * -(-n // 3)
+    np.testing.assert_array_equal(np.asarray(weight_p[n:]), 0)
+    np.testing.assert_array_equal(np.asarray(trace_p.cmd[n:]),
+                                  np.asarray(trace.cmd[:1]).repeat(
+                                      trace_p.cmd.shape[0] - n, axis=0))
+    same = fleet.pad_rows(trace, weight, n)
+    assert same[0] is trace and same[1] is weight
+
+
+@pytest.mark.skipif(jax.device_count() < 2,
+                    reason="needs the forced multi-device CPU lane")
+def test_sharded_fleet_surface_pads_non_dividing_fleet():
+    """A fleet that does not divide the model axis pads (module 0
+    replicated, sliced off) instead of dispatching to one device."""
+    from repro.launch.mesh import make_local_mesh
+    n_dev = jax.device_count()
+    mesh = make_local_mesh(data=1, model=n_dev)
+    trace, weight = _surface_batch()
+    _, pp = device_sim.synth_fleet_params(2 * n_dev + 1)
+    plain = fleet.fleet_surface_energy(pp, trace, weight)
+    sharded = fleet.fleet_surface_energy(pp, trace, weight, mesh=mesh)
+    for f in plain._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(plain, f)),
+                                      np.asarray(getattr(sharded, f)),
+                                      err_msg=f"leaf {f}")
+
+
 @pytest.mark.skipif(jax.device_count() < 2,
                     reason="needs the forced multi-device CPU lane")
 def test_sharded_run_probes_bitwise(tiny_fleet):

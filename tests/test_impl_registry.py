@@ -235,6 +235,13 @@ def test_interpret_default_resolves_per_call(monkeypatch):
     assert kcommon.interpret_default() is (jax.default_backend() != "tpu")
 
 
+def test_interpret_never_forced_on_tpu(monkeypatch):
+    monkeypatch.setattr(kcommon.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert kcommon.interpret_default() is False
+    assert model_api.impl_execution_mode("pallas") == "compiled"
+
+
 def test_impl_execution_mode_reports_fallback(monkeypatch):
     assert model_api.impl_execution_mode("vectorized") == "compiled"
     assert model_api.impl_execution_mode("reference") == "compiled"

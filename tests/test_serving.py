@@ -253,7 +253,7 @@ def test_treedef_stable_model_update_reuses_programs(quick_vampire):
 
 
 # ---------------------------------------------------------------------------
-# Mesh parity: single-host fallback everywhere, shard_map on the CI lane
+# Mesh parity: plain dispatch on one device, shard_map on the CI lane
 # ---------------------------------------------------------------------------
 def test_single_device_mesh_falls_back_bitwise(quick_vampire):
     trs = _sweeps()
@@ -287,7 +287,7 @@ def test_shard_map_matches_single_device_bitwise(quick_vampire):
         np.testing.assert_array_equal(
             np.asarray(svc_mesh.result(a).energy_pj),
             np.asarray(svc_none.result(b).energy_pj))
-    # a window that does not divide the mesh falls back, still exact
+    # a window that does not divide the mesh pads, still exact
     t3, _ = svc_mesh.submit_many(trs[:3])
     svc_mesh.drain()
     direct = quick_vampire.estimate(trs[:3])
