@@ -57,8 +57,6 @@ RTOL = 1e-4
 #: up to the ring's largest window
 WINDOWS = ((8, 256), (16, 1024), (32, 4096), (64, 16384))
 LM_ARCH = "qwen2.5-3b"
-BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 
 class SmokeFailure(RuntimeError):
@@ -100,30 +98,31 @@ def check_reports(actual, desired, what: str, exact: bool = False) -> None:
 
 
 class CompileLog:
-    """Counts XLA backend compiles (persistent-cache hits included, which
-    are cheap) through ``jax.monitoring``."""
+    """XLA backend compiles (persistent-cache loads included, which are
+    cheap) and persistent-cache hits since it was made, read from the
+    program's span recorder (``repro.runtime.spans``)."""
 
     def __init__(self):
-        self.count = 0
-        self.seconds = 0.0
-        self.cache_hits = 0
-
-    def install(self) -> None:
-        import jax
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, secs: float, **_) -> None:
-        if event == BACKEND_COMPILE:
-            self.count += 1
-            self.seconds += secs
-
-    def _event(self, event: str, **_) -> None:
-        if event == CACHE_HIT:
-            self.cache_hits += 1
+        from repro.runtime.spans import RECORDER
+        self._recorder = RECORDER
+        self._t0 = time.perf_counter()
+        self._hits0 = RECORDER.cache_hits
 
     def snapshot(self) -> tuple[int, float, int]:
-        return self.count, self.seconds, self.cache_hits
+        from repro.runtime.spans import COMPILE
+        recs = self._recorder.inside(self._t0, time.perf_counter())
+        if recs is None:
+            raise SmokeFailure("the span recorder dropped compile records")
+        secs = [r.t1 - r.t0 for r in recs if r.name == COMPILE]
+        return len(secs), sum(secs), self._recorder.cache_hits - self._hits0
+
+    @property
+    def count(self) -> int:
+        return self.snapshot()[0]
+
+    @property
+    def cache_hits(self) -> int:
+        return self.snapshot()[2]
 
 
 @contextlib.contextmanager
@@ -385,7 +384,6 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
     cache_dir = enable_compile_cache()
     log = CompileLog()
-    log.install()
     totals = {"wall_s": 0.0, "compile_s": 0.0}
     print(f"compile cache: {cache_dir}", flush=True)
 

@@ -44,6 +44,7 @@ from repro.core import dram
 from repro.core.dram import (ACT, CMD_NAMES, NOP, N_BANKS, PDE, PDE_SLOW,
                              PDX, PRE, PREA, RD, REF, SRE, SRX, TIMING, WR,
                              CommandTrace, _PDN_ILLEGAL, _SR_LEGAL)
+from repro.runtime.spans import span
 
 NEG = -(1 << 30)          # "never happened" sentinel time/index
 ERROR = "error"
@@ -452,25 +453,36 @@ def _get_batch_kernel():
         import jax
 
         @jax.jit
-        def kernel(cmd, bank, dt):
+        def lint_rules(cmd, bank, dt):
             def one(c, b, d):
                 return _eval_rules(c, b, d, _JaxBackend)
             return jax.vmap(one)(cmd, bank, dt)     # (T, R, N) each
 
-        _lint_batch_kernel = kernel
+        _lint_batch_kernel = lint_rules
     return _lint_batch_kernel
 
 
 def lint_arrays_batched(cmd, bank, dt) -> list[Diagnostic]:
-    """Lint a padded (T, N) command batch in one jitted dispatch."""
-    mask, deficit, bank_r = _get_batch_kernel()(cmd, bank, dt)
-    mask = np.asarray(mask)
-    deficit = np.asarray(deficit)
-    bank_r = np.asarray(bank_r)
-    cmd = np.asarray(cmd)
-    out = []
-    for ti in range(mask.shape[0]):
-        out.extend(_extract(mask[ti], deficit[ti], bank_r[ti], cmd[ti], ti))
+    """Lint a padded (T, N) command batch in one jitted dispatch.
+
+    Spans: ``lint.rules`` (the host planes to the device and the rule
+    program, waited for; ``bytes`` sent), ``lint.fetch`` (its three
+    (T, R, N) outputs to the host, ``bytes``) and ``lint.extract`` (the
+    diagnostics)."""
+    import jax
+    with span("lint.rules") as s:
+        s.attrs["bytes"] = sum(x.nbytes for x in (cmd, bank, dt)
+                               if isinstance(x, np.ndarray))
+        fired = jax.block_until_ready(_get_batch_kernel()(cmd, bank, dt))
+    with span("lint.fetch") as s:
+        mask, deficit, bank_r = (np.asarray(x) for x in fired)
+        s.attrs["bytes"] = mask.nbytes + deficit.nbytes + bank_r.nbytes
+    with span("lint.extract"):
+        cmd = np.asarray(cmd)
+        out = []
+        for ti in range(mask.shape[0]):
+            out.extend(_extract(mask[ti], deficit[ti], bank_r[ti], cmd[ti],
+                                ti))
     return out
 
 
@@ -492,16 +504,17 @@ def lint_traces(traces: Sequence[CommandTrace]) -> list[Diagnostic]:
     traces = list(traces)
     if not traces:
         return []
-    longest = max(int(tr.n) for tr in traces)
-    length = 1 << max(longest - 1, 1).bit_length()
-    cmd = np.zeros((len(traces), length), np.int32)   # NOP == 0
-    bank = np.zeros((len(traces), length), np.int32)
-    dt = np.zeros((len(traces), length), np.int32)
-    for i, tr in enumerate(traces):
-        n = int(tr.n)
-        cmd[i, :n] = np.asarray(tr.cmd)
-        bank[i, :n] = np.asarray(tr.bank)
-        dt[i, :n] = np.asarray(tr.dt)
+    with span("lint.pack"):
+        longest = max(int(tr.n) for tr in traces)
+        length = 1 << max(longest - 1, 1).bit_length()
+        cmd = np.zeros((len(traces), length), np.int32)   # NOP == 0
+        bank = np.zeros((len(traces), length), np.int32)
+        dt = np.zeros((len(traces), length), np.int32)
+        for i, tr in enumerate(traces):
+            n = int(tr.n)
+            cmd[i, :n] = np.asarray(tr.cmd)
+            bank[i, :n] = np.asarray(tr.bank)
+            dt[i, :n] = np.asarray(tr.dt)
     return lint_arrays_batched(cmd, bank, dt)
 
 

@@ -34,6 +34,7 @@ from repro.core.energy_model import (PowerParams, _report,
                                      charge_from_features,
                                      extract_structural_features,
                                      finalize_features, masked_totals)
+from repro.runtime.spans import span
 
 
 def stack_params(params: Sequence[PowerParams]) -> PowerParams:
@@ -115,19 +116,15 @@ class FleetStackCache:
         self.maxsize = maxsize
         self._entries: dict = {}     # key -> (modules_ref, stacked)
         self._order: list = []
-        self.hits = 0
-        self.misses = 0
 
     def stacked(self, modules, mesh=None) -> PowerParams:
         from repro.core import model_api
         key = (tuple(id(m) for m in modules), mesh)
         hit = self._entries.get(key)
         if hit is not None:
-            self.hits += 1
             self._order.remove(key)
             self._order.append(key)
             return hit[1]
-        self.misses += 1
         stacked = stack_params([m.params for m in modules])
         axis = None
         if mesh is not None and mesh.shape.get("model", 1) > 1 \
@@ -271,37 +268,42 @@ def fleet_surface_energy(modules, trace: CommandTrace, weight: jax.Array,
     (``estimate_batch.chunked_surface_reports``) — exact parity with the
     one-shot path, live memory bounded to one chunk's intermediates, the
     fleet-scale path for 10k+ module fleets.  Chunking and mesh sharding
-    are mutually exclusive (pass one or the other)."""
+    are mutually exclusive (pass one or the other).
+
+    The ``fleet.surface`` span times the host call: dispatch and the
+    report's finalization, not the device work it enqueues."""
     from repro.core import estimate_batch, model_api
     impl = model_api.resolve_impl(impl, mode="surface").name
     if impl == "reference":
         raise ValueError("impl='reference' for the fleet surface is the "
                          "per-command oracle; score modules one at a time")
-    if module_chunk is not None or trace_chunk is not None:
-        if mesh is not None:
-            raise ValueError("module_chunk/trace_chunk and mesh are "
-                             "mutually exclusive surface strategies")
-        stacked = fleet_stacked(modules)
-        return estimate_batch.chunked_surface_reports(
-            trace, weight, stacked,
-            module_chunk=(stacked.i2n.shape[0] if module_chunk is None
-                          else module_chunk),
-            trace_chunk=trace_chunk, impl=impl)
-    stacked = fleet_stacked(modules, mesh)
-    n_data, n_model = mesh_split(mesh)
-    if n_data * n_model > 1:
-        n_traces, n_modules = trace.cmd.shape[0], stacked.i2n.shape[0]
-        trace_p, weight_p = pad_rows(trace, weight, n_data)
-        stacked_p = pad_leading(stacked, (-n_modules) % n_model)
-        charge = _sharded_surface_fn(mesh, impl == "pallas")(
-            trace_p, weight_p, stacked_p)[:n_traces, :n_modules]
-        cycles = estimate_batch._surface_cycles_batch(trace, weight)
-        return _report(charge,
-                       jnp.broadcast_to(cycles[:, None], charge.shape))
-    dispatch = (estimate_batch.pallas_batched_surface_reports
-                if impl == "pallas"
-                else estimate_batch.batched_surface_reports)
-    return dispatch(trace, weight, stacked)
+    chunked = module_chunk is not None or trace_chunk is not None
+    if chunked and mesh is not None:
+        raise ValueError("module_chunk/trace_chunk and mesh are "
+                         "mutually exclusive surface strategies")
+    with span("fleet.surface"):
+        if chunked:
+            stacked = fleet_stacked(modules)
+            return estimate_batch.chunked_surface_reports(
+                trace, weight, stacked,
+                module_chunk=(stacked.i2n.shape[0] if module_chunk is None
+                              else module_chunk),
+                trace_chunk=trace_chunk, impl=impl)
+        stacked = fleet_stacked(modules, mesh)
+        n_data, n_model = mesh_split(mesh)
+        if n_data * n_model > 1:
+            n_traces, n_modules = trace.cmd.shape[0], stacked.i2n.shape[0]
+            trace_p, weight_p = pad_rows(trace, weight, n_data)
+            stacked_p = pad_leading(stacked, (-n_modules) % n_model)
+            charge = _sharded_surface_fn(mesh, impl == "pallas")(
+                trace_p, weight_p, stacked_p)[:n_traces, :n_modules]
+            cycles = estimate_batch._surface_cycles_batch(trace, weight)
+            return _report(charge,
+                           jnp.broadcast_to(cycles[:, None], charge.shape))
+        dispatch = (estimate_batch.pallas_batched_surface_reports
+                    if impl == "pallas"
+                    else estimate_batch.batched_surface_reports)
+        return dispatch(trace, weight, stacked)
 
 
 @functools.lru_cache(maxsize=8)

@@ -93,6 +93,8 @@ def baseline_energy_pallas(kind: str, planes: dict, any_act, table,
     args = [planes[n].astype(jnp.float32) for n in PLANES]
     args.append(jnp.broadcast_to(any_act.astype(jnp.float32)[:, None],
                                  args[0].shape))
-    return energy_grid_call(_CHARGE_FNS[kind], args, table, cells=cells,
+    return energy_grid_call(_CHARGE_FNS[kind], args, table,
+                            name=(f"{kind}_energy" if cells is None
+                                  else f"{kind}_surface"), cells=cells,
                             n_cells=N_SURFACE_CELLS, block_n=block_n,
                             interpret=interpret, grid_layout=grid_layout)

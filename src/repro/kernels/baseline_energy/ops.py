@@ -20,10 +20,10 @@ from repro.kernels.common import interpret_default, pad_batch
 @functools.partial(jax.jit,
                    static_argnames=("kind", "surface", "block_n",
                                     "interpret", "grid_layout"))
-def _charge_matrix(trace: CommandTrace, weight, tiled: CommandTrace,
-                   w_tiled, table, kind: str,
-                   surface: bool, block_n: int, interpret: bool,
-                   grid_layout: str):
+def _baseline_charge_matrix(trace: CommandTrace, weight,
+                            tiled: CommandTrace, w_tiled, table, kind: str,
+                            surface: bool, block_n: int, interpret: bool,
+                            grid_layout: str):
     t = trace.cmd.shape[0]
     st = jax.vmap(structural_state)(tiled)
     planes = {
@@ -73,5 +73,6 @@ def baseline_charge_matrix(trace: CommandTrace, weight, table, kind: str, *,
         grid_layout = (cfg["layout"] if grid_layout is None
                        else grid_layout)
     tiled, w_tiled = pad_batch(trace, weight, block_n)
-    return _charge_matrix(trace, weight, tiled, w_tiled, table, kind,
-                          surface, block_n, interpret, grid_layout)
+    return _baseline_charge_matrix(trace, weight, tiled, w_tiled, table,
+                                   kind, surface, block_n, interpret,
+                                   grid_layout)

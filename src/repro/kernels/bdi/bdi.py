@@ -114,5 +114,6 @@ def bdi_sizes_pallas(bytes_i32: jax.Array, block_n: int = BLOCK_N,
         out_shape=[jax.ShapeDtypeStruct((x.shape[0],), jnp.int32),
                    jax.ShapeDtypeStruct((x.shape[0],), jnp.int32)],
         interpret=interpret,
+        name="bdi",
     )(x)
     return sizes[:n], schemes[:n]

@@ -48,5 +48,6 @@ def byte_lut_pallas(b: jax.Array, lut: jax.Array, block_b: int = BLOCK_B,
         out_specs=pl.BlockSpec((block_b,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0],), jnp.int32),
         interpret=interpret,
+        name="byte_lut",
     )(x, lut.astype(jnp.int32))
     return out[:n]

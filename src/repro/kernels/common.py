@@ -126,9 +126,9 @@ def _cell_partials(cw, cells, n_cells: int):
     return acc
 
 
-def energy_grid_call(charge_fn, planes, table, *, vendor_planes=(),
-                     cells=None, n_cells: int = 0, block_n: int,
-                     interpret: bool, grid_layout: str = "vti"):
+def energy_grid_call(charge_fn, planes, table, *, name: str,
+                     vendor_planes=(), cells=None, n_cells: int = 0,
+                     block_n: int, interpret: bool, grid_layout: str = "vti"):
     """Launch a fused per-command charge body over the ``(vendors, trace
     blocks, command blocks)`` grid and reduce it.
 
@@ -140,10 +140,12 @@ def energy_grid_call(charge_fn, planes, table, *, vendor_planes=(),
     The trace axis pads to a multiple of 8 and the command axis to
     ``block_n`` with zeros — pad slots must carry zero weight.
 
-    Returns the (T, V) charge matrix, or with ``cells`` (the (T, N) int32
-    cell index of every command) the (T, V, n_cells) decomposition.  The
-    whole reduction runs in the kernel, so the result of a trace or vendor
-    does not depend on how many others share the launch.
+    ``name`` names the launch in the compiled program and the device
+    trace.  Returns the (T, V) charge matrix, or with ``cells`` (the
+    (T, N) int32 cell index of every command) the (T, V, n_cells)
+    decomposition.  The whole reduction runs in the kernel, so the result
+    of a trace or vendor does not depend on how many others share the
+    launch.
     ``grid_layout`` is pure scheduling: every grid cell computes the same
     partial sums either way."""
     if block_n % LANES:
@@ -211,6 +213,7 @@ def energy_grid_call(charge_fn, planes, table, *, vendor_planes=(),
         out_shape=jax.ShapeDtypeStruct((n_vendors, t_pad, LANES),
                                        jnp.float32),
         interpret=interpret,
+        name=name,
     )(*args, table.astype(jnp.float32)[:, None, :])
     # every sum happened in the kernel, in an order fixed by the tile
     # shapes alone, so sharding the trace or vendor axis cannot change a

@@ -109,4 +109,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -42,5 +42,6 @@ def line_ones_pallas(lines: jax.Array, block_n: int = BLOCK_N,
         out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0],), jnp.int32),
         interpret=interpret,
+        name="popcount",
     )(x)
     return out[:n]

@@ -40,5 +40,6 @@ def line_toggles_pallas(cur: jax.Array, prev: jax.Array,
         out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((cur.shape[0],), jnp.int32),
         interpret=interpret,
+        name="toggle",
     )(cur, prev)
     return out[:n]

@@ -37,10 +37,11 @@ from repro.kernels.vampire_energy.vampire_energy import (
 @functools.partial(jax.jit,
                    static_argnames=("surface", "block_n", "interpret",
                                     "grid_layout"))
-def _charge_matrix(trace: CommandTrace, weight, tiled: CommandTrace,
-                   w_tiled, stacked: PowerParams,
-                   ones_frac, toggle_frac, surface: bool, block_n: int,
-                   interpret: bool, grid_layout: str):
+def _vampire_charge_matrix(trace: CommandTrace, weight,
+                           tiled: CommandTrace, w_tiled,
+                           stacked: PowerParams, ones_frac, toggle_frac,
+                           surface: bool, block_n: int, interpret: bool,
+                           grid_layout: str):
     t = trace.cmd.shape[0]
     st = jax.vmap(structural_state)(tiled)
     if ones_frac is None:
@@ -123,9 +124,9 @@ def batched_charge_matrix(trace: CommandTrace, weight, stacked: PowerParams,
         grid_layout = (cfg["layout"] if grid_layout is None
                        else grid_layout)
     tiled, w_tiled = pad_batch(trace, weight, block_n)
-    return _charge_matrix(trace, weight, tiled, w_tiled, stacked, ones_frac,
-                          toggle_frac, surface, block_n, interpret,
-                          grid_layout)
+    return _vampire_charge_matrix(trace, weight, tiled, w_tiled, stacked,
+                                  ones_frac, toggle_frac, surface, block_n,
+                                  interpret, grid_layout)
 
 
 def trace_energy_kernel(trace: CommandTrace, pp: PowerParams) -> EnergyReport:

@@ -120,6 +120,7 @@ def batched_features_pallas(data, prev, tmask, block_n: int = BLOCK_N,
         out_specs=[spec_t, spec_t],
         out_shape=[plane, plane],
         interpret=interpret,
+        name="vampire_features",
     )(data, prev, tmask)
     return ones[:n_traces, :n_cmds], togg[:n_traces, :n_cmds]
 
@@ -208,6 +209,8 @@ def batched_energy_pallas(feats: dict, table, block_n: int = BLOCK_N,
         interpret = interpret_default()
     planes = [feats[n] for n in FEATURE_PLANES]
     return energy_grid_call(_masked_charge, planes, table,
+                            name=("vampire_energy" if cells is None
+                                  else "vampire_surface"),
                             vendor_planes=[feats["surf"]], cells=cells,
                             n_cells=N_SURFACE_CELLS, block_n=block_n,
                             interpret=interpret, grid_layout=grid_layout)

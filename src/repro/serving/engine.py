@@ -37,6 +37,7 @@ import jax
 from repro.core import model_api
 from repro.core.estimate_batch import TraceBatch
 from repro.core.fleet import pad_rows
+from repro.runtime.spans import span
 
 
 class ServingEngine:
@@ -70,15 +71,19 @@ class ServingEngine:
         """Score one bucket-shaped batch -> the model's report (leaves
         (traces, vendors)-shaped; mode='range' a (lo, mean, hi) triple).
         Shards the trace axis when the mesh has >1 device, padding it to
-        a multiple of the device count; identical numerics either way."""
+        a multiple of the device count; identical numerics either way.
+        The ``engine.dispatch`` span times the program lookup and the
+        enqueue, not the device work."""
         vendors = (tuple(int(v) for v in vendors)
                    if vendors is not None else None)
-        if self.n_shards == 1:
-            return self._dispatch_fn(vendors, False)(
-                self.resident, tb.trace, tb.weight)
-        trace, weight = pad_rows(tb.trace, tb.weight, self.n_shards)
-        out = self._dispatch_fn(vendors, True)(self.resident, trace, weight)
-        return jax.tree_util.tree_map(lambda x: x[:tb.n_traces], out)
+        with span("engine.dispatch"):
+            if self.n_shards == 1:
+                return self._dispatch_fn(vendors, False)(
+                    self.resident, tb.trace, tb.weight)
+            trace, weight = pad_rows(tb.trace, tb.weight, self.n_shards)
+            out = self._dispatch_fn(vendors, True)(self.resident, trace,
+                                                   weight)
+            return jax.tree_util.tree_map(lambda x: x[:tb.n_traces], out)
 
     def _dispatch_fn(self, vendors, sharded: bool):
         # The model rides as a traced ARGUMENT, not a closure: the jit
@@ -87,7 +92,7 @@ class ServingEngine:
         # compiled program instead of recompiling the world.
         fn = self._fns.get((vendors, sharded))
         if fn is None:
-            def call(m, trace, weight):
+            def serve_estimate(m, trace, weight):
                 return m.estimate(
                     TraceBatch(trace, weight), vendors, mode=self.mode,
                     impl=self.impl, ones_frac=self.ones_frac,
@@ -96,10 +101,11 @@ class ServingEngine:
             if sharded:
                 from jax.sharding import PartitionSpec as P
                 spec = P(tuple(self.mesh.axis_names))
-                call = jax.shard_map(call, mesh=self.mesh,
-                                     in_specs=(P(), spec, spec),
-                                     out_specs=spec, check_vma=False)
-            fn = jax.jit(call)
+                serve_estimate = jax.shard_map(
+                    serve_estimate, mesh=self.mesh,
+                    in_specs=(P(), spec, spec), out_specs=spec,
+                    check_vma=False)
+            fn = jax.jit(serve_estimate)
             self._fns[(vendors, sharded)] = fn
         return fn
 

@@ -32,13 +32,16 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import time
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dram import LINE_WORDS, CommandTrace
 from repro.core.estimate_batch import TraceBatch
+from repro.runtime.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,7 +134,8 @@ class TraceRing:
         if ticket is None:
             ticket = self._next_ticket
         self._next_ticket = max(self._next_ticket, ticket) + 1
-        self._pending.append((int(ticket), trace, group))
+        self._pending.append((int(ticket), trace, group,
+                              time.perf_counter()))
         return int(ticket)
 
     # ------------------------------------------------------------ dispatch
@@ -140,45 +144,54 @@ class TraceRing:
         shape.  Entries sharing the head entry's ``group`` (vendor-subset
         key) are collected FIFO up to ``max_batch``; other groups keep
         their order for later ticks.  Returns None when the ring is empty
-        (the empty flush is a no-op, not an error)."""
+        (the empty flush is a no-op, not an error).
+
+        Spans: ``ring.repad`` (group pick and the host re-pad; counts
+        ``n_real`` traces, ``real_cmds``/``slot_cmds`` commands and
+        ``wait_s``, the taken traces' summed wait from admission to this
+        take) and ``ring.transfer`` (the buffers to the device, waited
+        for so that the whole copy lies in the span; ``bytes``)."""
         if not self._pending:
             return None
-        limit = min(max_batch or self.config.max_batch,
-                    self.config.max_batch)
-        group = self._pending[0][2]
-        picked, kept = [], []
-        for entry in self._pending:
-            if entry[2] == group and len(picked) < limit:
-                picked.append(entry)
-            else:
-                kept.append(entry)
-        self._pending = collections.deque(kept)
+        with span("ring.repad") as s:
+            limit = min(max_batch or self.config.max_batch,
+                        self.config.max_batch)
+            group = self._pending[0][2]
+            picked, kept = [], []
+            for entry in self._pending:
+                if entry[2] == group and len(picked) < limit:
+                    picked.append(entry)
+                else:
+                    kept.append(entry)
+            self._pending = collections.deque(kept)
 
-        tickets = tuple(t for t, _, _ in picked)
-        trs = [tr for _, tr, _ in picked]
-        cbucket = bucket_for(len(trs), self.config.count_buckets)
-        lbucket = bucket_for(max(int(tr.n) for tr in trs),
-                             self.config.length_buckets)
-        buf = self._buffers_for(cbucket, lbucket)
-        for arr in buf.values():
-            arr.fill(0)                      # NOP == 0, dt == 0, weight == 0
-        for i, tr in enumerate(trs):
-            n = int(tr.n)
-            buf["cmd"][i, :n] = np.asarray(tr.cmd)
-            buf["bank"][i, :n] = np.asarray(tr.bank)
-            buf["row"][i, :n] = np.asarray(tr.row)
-            buf["col"][i, :n] = np.asarray(tr.col)
-            buf["data"][i, :n] = np.asarray(tr.data)
-            buf["dt"][i, :n] = np.asarray(tr.dt)
-            buf["weight"][i, :n] = 1.0
-        batch = CommandTrace(cmd=jnp.asarray(buf["cmd"]),
-                             bank=jnp.asarray(buf["bank"]),
-                             row=jnp.asarray(buf["row"]),
-                             col=jnp.asarray(buf["col"]),
-                             data=jnp.asarray(buf["data"]),
-                             dt=jnp.asarray(buf["dt"]))
-        return RingBatch(TraceBatch(batch, jnp.asarray(buf["weight"])),
-                         tickets, group)
+            tickets = tuple(e[0] for e in picked)
+            trs = [e[1] for e in picked]
+            cbucket = bucket_for(len(trs), self.config.count_buckets)
+            lbucket = bucket_for(max(int(tr.n) for tr in trs),
+                                 self.config.length_buckets)
+            buf = self._buffers_for(cbucket, lbucket)
+            for arr in buf.values():
+                arr.fill(0)                  # NOP == 0, dt == 0, weight == 0
+            for i, tr in enumerate(trs):
+                n = int(tr.n)
+                buf["cmd"][i, :n] = np.asarray(tr.cmd)
+                buf["bank"][i, :n] = np.asarray(tr.bank)
+                buf["row"][i, :n] = np.asarray(tr.row)
+                buf["col"][i, :n] = np.asarray(tr.col)
+                buf["data"][i, :n] = np.asarray(tr.data)
+                buf["dt"][i, :n] = np.asarray(tr.dt)
+                buf["weight"][i, :n] = 1.0
+            s.attrs.update(n_real=len(trs), real_cmds=sum(int(tr.n) for tr in trs),
+                           slot_cmds=cbucket * lbucket,
+                           wait_s=sum(s.t0 - e[3] for e in picked))
+        with span("ring.transfer") as s:
+            dev = jax.block_until_ready(
+                {k: jnp.asarray(v) for k, v in buf.items()})
+            s.attrs["bytes"] = sum(v.nbytes for v in buf.values())
+        batch = CommandTrace(cmd=dev["cmd"], bank=dev["bank"], row=dev["row"],
+                             col=dev["col"], data=dev["data"], dt=dev["dt"])
+        return RingBatch(TraceBatch(batch, dev["weight"]), tickets, group)
 
     def _buffers_for(self, count: int, length: int) -> dict[str, np.ndarray]:
         buf = self._buffers.get((count, length))

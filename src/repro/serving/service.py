@@ -28,6 +28,7 @@ bounds).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from typing import Sequence
@@ -36,8 +37,13 @@ import jax
 import numpy as np
 
 from repro.core.dram import CommandTrace
+from repro.runtime.spans import span
 from repro.serving.engine import ServingEngine
 from repro.serving.ring import RingConfig, TraceRing, TraceTooLongError
+
+#: completions (dispatches) the latency (dispatch-time and fill) samples
+#: keep, and rejections :attr:`EstimationService.rejections` keeps
+RECENT = 4096
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,7 +81,9 @@ class Rejection:
 
 @dataclasses.dataclass(frozen=True)
 class MetricsSnapshot:
-    """Counters since service construction (one dispatch granularity)."""
+    """Counters since service construction (one dispatch granularity).
+    ``batch_fill`` and the percentiles are over the last :data:`RECENT`
+    dispatches and completions."""
     admitted: int
     rejected: int
     rejected_by_rule: dict[str, int]
@@ -84,8 +92,8 @@ class MetricsSnapshot:
     completed: int
     queue_depth: int
     batch_fill: float            # mean real-slots / padded-slots
-    traces_per_s: float          # admitted traces through dispatch time
-    latency_p50_ms: float        # submit -> result available
+    traces_per_s: float          # completed / (last completion - first admit)
+    latency_p50_ms: float        # submit -> result available (sliced)
     latency_p99_ms: float
     dispatch_p50_ms: float       # one engine dispatch, block_until_ready
     dispatch_p99_ms: float
@@ -97,7 +105,7 @@ class MetricsSnapshot:
     recalibrations: int = 0      # refits pushed through update_model
 
 
-def _pct(samples: list[float], q: float) -> float:
+def _pct(samples: Sequence[float], q: float) -> float:
     return float(np.percentile(np.asarray(samples), q) * 1e3) \
         if samples else 0.0
 
@@ -133,13 +141,19 @@ class EstimationService:
         # counters
         self._admitted = 0
         self._rejected_by_rule: dict[str, int] = {}
-        self._rejections: list[Rejection] = []
+        self._rejected = 0
+        self._rejections: collections.deque = collections.deque(
+            maxlen=RECENT)
         self._dispatches = 0
         self._dispatched = 0
         self._completed = 0
-        self._fills: list[float] = []
-        self._dispatch_s: list[float] = []
-        self._latency_s: list[float] = []
+        self._first_admit_t: float | None = None
+        self._last_done_t = 0.0
+        self._fills: collections.deque = collections.deque(maxlen=RECENT)
+        self._dispatch_s: collections.deque = collections.deque(
+            maxlen=RECENT)
+        self._latency_s: collections.deque = collections.deque(
+            maxlen=RECENT)
 
     # ----------------------------------------------------------- admission
     def submit(self, trace: CommandTrace,
@@ -188,11 +202,14 @@ class EstimationService:
                 tickets.append(None)
                 continue
             self._submit_t[ticket] = now
+            if self._first_admit_t is None:
+                self._first_admit_t = now
             self._admitted += 1
             tickets.append(ticket)
         return tickets, rejections
 
     def _reject(self, r: Rejection) -> Rejection:
+        self._rejected += 1
         self._rejections.append(r)
         for rule in r.rules:
             self._rejected_by_rule[rule] = \
@@ -202,24 +219,34 @@ class EstimationService:
     # ------------------------------------------------------------ dispatch
     def step(self) -> int:
         """Dispatch ONE ring window; returns how many real traces it
-        scored (0 on an empty ring — the empty flush is a no-op)."""
+        scored (0 on an empty ring — the empty flush is a no-op).
+
+        Spans (besides the ring's and the engine's): ``engine.block``,
+        the wait for the device, and ``service.slice``, the report to the
+        host and its per-ticket rows (``bytes`` fetched)."""
         rb = self.ring.take(self.config.max_batch)
         if rb is None:
             return 0
         t0 = time.perf_counter()
         rep = self.engine.dispatch(rb.batch, rb.group)
-        jax.block_until_ready(rep)
+        with span("engine.block"):
+            jax.block_until_ready(rep)
         t1 = time.perf_counter()
         self._last_dispatch_t = t1
         self._dispatches += 1
         self._dispatched += rb.n_real
         self._fills.append(rb.fill)
         self._dispatch_s.append(t1 - t0)
-        for i, ticket in enumerate(rb.tickets):
-            self._results[ticket] = jax.tree_util.tree_map(
-                lambda x: np.asarray(x)[i], rep)
-            self._latency_s.append(t1 - self._submit_t.pop(ticket, t0))
-            self._completed += 1
+        with span("service.slice") as s:
+            s.attrs["bytes"] = sum(x.nbytes
+                                   for x in jax.tree_util.tree_leaves(rep))
+            for i, ticket in enumerate(rb.tickets):
+                self._results[ticket] = jax.tree_util.tree_map(
+                    lambda x: np.asarray(x)[i], rep)
+                done = time.perf_counter()
+                self._latency_s.append(done - self._submit_t.pop(ticket, t0))
+                self._completed += 1
+        self._last_done_t = done
         return rb.n_real
 
     def maybe_step(self) -> int:
@@ -278,22 +305,23 @@ class EstimationService:
 
     @property
     def rejections(self) -> tuple[Rejection, ...]:
+        """The last :data:`RECENT` rejections, oldest first."""
         return tuple(self._rejections)
 
     # ------------------------------------------------------------- metrics
     def metrics(self) -> MetricsSnapshot:
-        dispatch_time = sum(self._dispatch_s)
+        wall = (self._last_done_t - self._first_admit_t
+                if self._first_admit_t is not None else 0.0)
         return MetricsSnapshot(
             admitted=self._admitted,
-            rejected=len(self._rejections),
+            rejected=self._rejected,
             rejected_by_rule=dict(self._rejected_by_rule),
             dispatches=self._dispatches,
             dispatched_traces=self._dispatched,
             completed=self._completed,
             queue_depth=len(self.ring),
             batch_fill=float(np.mean(self._fills)) if self._fills else 0.0,
-            traces_per_s=(self._dispatched / dispatch_time
-                          if dispatch_time > 0 else 0.0),
+            traces_per_s=self._completed / wall if wall > 0 else 0.0,
             latency_p50_ms=_pct(self._latency_s, 50),
             latency_p99_ms=_pct(self._latency_s, 99),
             dispatch_p50_ms=_pct(self._dispatch_s, 50),
