@@ -10,9 +10,15 @@ timing/state rule the generators must obey into a registered
 * :func:`lint_trace` — single trace, numpy, the construction-time hook the
   repo's generators call through :func:`check_generated`;
 * :func:`lint_batch` / :func:`lint_traces` — the whole padded
-  :class:`~repro.core.estimate_batch.TraceBatch` linted in ONE jitted
-  dispatch (vectorized cumulative-index/segment passes, no per-command
-  Python), for serving ingestion and the CI corpus sweep;
+  :class:`~repro.core.estimate_batch.TraceBatch` linted by jitted
+  programs (vectorized cumulative-index/segment passes, no per-command
+  Python), for serving ingestion and the CI corpus sweep.  Two programs
+  share the rules: ``lint_count`` reduces every trace to its number of
+  fired cells on the device, and only the traces that fired run again
+  through ``lint_rules`` for their full diagnostics
+  (:func:`lint_arrays_batched`).  The diagnostics are those of
+  ``lint_rules`` over every trace; a clean batch fetches T counts, a
+  batch where every trace fires pays both programs;
 * :func:`reference_lint` — an independent per-command Python walk kept as
   the parity oracle (and the benchmark comparator).
 
@@ -34,6 +40,7 @@ clean — JEDEC IDD loops measure with refresh suspended.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import warnings
 from typing import Callable, Sequence
@@ -442,59 +449,87 @@ def lint_trace(trace: CommandTrace, trace_index: int = 0) -> list[Diagnostic]:
     return _extract(mask, deficit, bank_r, cmd, trace_index)
 
 
-_lint_batch_kernel = None
+@functools.cache
+def _programs():
+    """``(lint_count, lint_rules)``, the two jitted (T, N) batch programs,
+    built lazily (keeps numpy-only callers of :func:`lint_trace` free of
+    any jax dispatch).  ``lint_count`` gives each trace's number of fired
+    (rule, command) cells, (T,) int32: XLA fuses the masks into the sum
+    and drops the deficit and bank stacks.  ``lint_rules`` gives the
+    three (T, R, N) stacks (mask, deficit, bank)."""
+    import jax
 
+    def stacks(cmd, bank, dt):
+        return jax.vmap(lambda c, b, d: _eval_rules(c, b, d, _JaxBackend))(
+            cmd, bank, dt)
 
-def _get_batch_kernel():
-    """The jitted (T, N) batch linter, built lazily (keeps numpy-only
-    callers of :func:`lint_trace` free of any jax dispatch)."""
-    global _lint_batch_kernel
-    if _lint_batch_kernel is None:
-        import jax
+    @jax.jit
+    def lint_count(cmd, bank, dt):
+        return stacks(cmd, bank, dt)[0].sum(axis=(1, 2), dtype="int32")
 
-        @jax.jit
-        def lint_rules(cmd, bank, dt):
-            def one(c, b, d):
-                return _eval_rules(c, b, d, _JaxBackend)
-            return jax.vmap(one)(cmd, bank, dt)     # (T, R, N) each
+    @jax.jit
+    def lint_rules(cmd, bank, dt):
+        return stacks(cmd, bank, dt)
 
-        _lint_batch_kernel = lint_rules
-    return _lint_batch_kernel
+    return lint_count, lint_rules
 
 
 def lint_arrays_batched(cmd, bank, dt) -> list[Diagnostic]:
-    """Lint a padded (T, N) command batch in one jitted dispatch.
+    """Lint a padded (T, N) command batch: count on the device, fetch what
+    fired.
 
-    Spans: ``lint.rules`` (the host planes to the device and the rule
-    program, waited for; ``bytes`` sent), ``lint.fetch`` (its three
-    (T, R, N) outputs to the host, ``bytes``) and ``lint.extract`` (the
-    diagnostics)."""
+    ``lint_count`` runs over every row and only its (T,) counts come to
+    the host.  The traces that fired, and only they, run again through
+    ``lint_rules``, whose (k, R, N) stacks are extracted; k is padded to a
+    power of two (at most T) with NOP/dt=0 rows, inert under every rule,
+    so compiled shapes stay bounded.  The diagnostics are exactly those
+    of ``lint_rules`` over every row.  A batch where every trace fires
+    (the tests' seeded violations) pays ``lint_count`` and a
+    second trip of its planes on top of ``lint_rules`` over every row;
+    served traffic, which never fires, pays ``lint_count`` alone.
+
+    Spans: ``lint.rules`` (the host planes to the device and
+    ``lint_count``, waited for; ``bytes`` sent), ``lint.fetch`` (the
+    counts down, and the fired traces' planes up and stacks down;
+    ``bytes`` moved, ``fired_traces`` re-run) and ``lint.extract`` (the
+    diagnostics), each recorded on every call."""
     import jax
+    lint_count, lint_rules = _programs()
     with span("lint.rules") as s:
         s.attrs["bytes"] = sum(x.nbytes for x in (cmd, bank, dt)
                                if isinstance(x, np.ndarray))
-        fired = jax.block_until_ready(_get_batch_kernel()(cmd, bank, dt))
+        counts = jax.block_until_ready(lint_count(cmd, bank, dt))
     with span("lint.fetch") as s:
-        mask, deficit, bank_r = (np.asarray(x) for x in fired)
-        s.attrs["bytes"] = mask.nbytes + deficit.nbytes + bank_r.nbytes
+        counts = np.asarray(counts)
+        fired = np.flatnonzero(counts)
+        s.attrs["bytes"] = counts.nbytes
+        s.attrs["fired_traces"] = len(fired)
+        if len(fired):
+            pad = min(1 << (len(fired) - 1).bit_length(), len(counts)) \
+                - len(fired)
+            planes = [np.pad(np.asarray(x)[fired], ((0, pad), (0, 0)))
+                      for x in (cmd, bank, dt)]
+            found = [np.asarray(x) for x in lint_rules(*planes)]
+            s.attrs["bytes"] += sum(x.nbytes for x in planes + found)
     with span("lint.extract"):
-        cmd = np.asarray(cmd)
         out = []
-        for ti in range(mask.shape[0]):
-            out.extend(_extract(mask[ti], deficit[ti], bank_r[ti], cmd[ti],
-                                ti))
+        for k, ti in enumerate(fired.tolist()):
+            mask, deficit, bank_r = (x[k] for x in found)
+            out.extend(_extract(mask, deficit, bank_r, planes[0][k], ti))
     return out
 
 
 def lint_batch(tb) -> list[Diagnostic]:
-    """Lint a prebuilt :class:`~repro.core.estimate_batch.TraceBatch` in one
-    jitted dispatch.  NOP/dt=0 padding is inert under every rule, so no
+    """Lint a prebuilt :class:`~repro.core.estimate_batch.TraceBatch`
+    through :func:`lint_arrays_batched`.  NOP/dt=0 padding is inert under every rule, so no
     weight masking is needed — pad rows simply cannot violate anything."""
     return lint_arrays_batched(tb.trace.cmd, tb.trace.bank, tb.trace.dt)
 
 
 def lint_traces(traces: Sequence[CommandTrace]) -> list[Diagnostic]:
-    """Lint a sequence of ragged traces through the batched engine, padding
+    """Lint a sequence of ragged traces through the batched engine
+    (:func:`lint_arrays_batched`: count on the device, full diagnostics
+    for the traces that fired, the same list as one full pass), padding
     to the next power of two so repeated calls share compiled shapes.
 
     Only the three fields the rules read are padded (host-side, one
@@ -717,8 +752,8 @@ def check_trace(trace: CommandTrace, origin: str = "make_trace",
 def lint_ingested(traces: Sequence[CommandTrace],
                   origin: str = "ingestion") -> None:
     """Strict batched gate for externally ingested traces (the serving
-    ``--power-report`` path): one jitted lint dispatch over the whole
-    sequence, raising with rule id + command index on any ERROR."""
+    ``--power-report`` path): one batched lint over the whole sequence
+    (:func:`lint_traces`), raising with rule id + command index on any ERROR."""
     errors = errors_of(lint_traces(traces))
     if errors:
         raise TraceProtocolError(errors, origin)

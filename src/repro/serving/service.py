@@ -166,7 +166,7 @@ class EstimationService:
     def submit_many(self, traces: Sequence[CommandTrace],
                     vendors: Sequence[int] | None = None
                     ) -> tuple[list[int | None], list[Rejection]]:
-        """Admit a burst: ONE batched lint dispatch over the whole burst,
+        """Admit a burst: ONE batched lint over the whole burst,
         then per-trace admission.  Illegal traces become
         :class:`Rejection`\\ s (their slot in ``tickets`` is ``None``);
         the legal ones are admitted regardless — a mixed burst never

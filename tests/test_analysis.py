@@ -3,6 +3,7 @@ linter (seeded-mutation per-rule coverage + engine parity), the
 compile-time dispatch auditor, and the repo AST lint."""
 import ast
 import textwrap
+import time
 
 import numpy as np
 import pytest
@@ -13,6 +14,7 @@ from repro.analysis import dispatch_audit, repo_lint, trace_lint
 from repro.core import dram, idd_loops, traces
 from repro.core.dram import (ACT, NOP, PDE, PDE_SLOW, PDX, PRE, PREA, RD,
                              REF, SRE, SRX, TIMING, WR)
+from repro.runtime.spans import RECORDER
 
 T = TIMING
 
@@ -139,9 +141,21 @@ _STEP = st.tuples(_CMDS, st.integers(min_value=0, max_value=7),
                   st.integers(min_value=0, max_value=2 * T.tRC))
 
 
+@pytest.fixture(scope="module")
+def compiled_lint_programs():
+    """Both batched lint programs compiled, for one trace, at every padded
+    length a 1-40 step script reaches (2 through 64), so that no
+    Hypothesis example is timed through a compile."""
+    for length in (2, 4, 8, 16, 32, 64):
+        for first in (NOP, RD):                 # clean; BANK_RW_CLOSED
+            script = [(first, 0, 1)] + [(NOP, 0, 1)] * (length - 1)
+            trace_lint.lint_traces([raw_trace(script)])
+
+
 @settings(max_examples=30)
 @given(script=st.lists(_STEP, min_size=1, max_size=40))
-def test_property_engines_agree_on_arbitrary_streams(script):
+def test_property_engines_agree_on_arbitrary_streams(compiled_lint_programs,
+                                                      script):
     """The vectorized numpy engine, the jitted batched engine, and the
     independent reference walk produce identical diagnostics for ANY
     command stream, legal or not."""
@@ -152,6 +166,57 @@ def test_property_engines_agree_on_arbitrary_streams(script):
     ref = key(trace_lint.reference_lint(tr))
     bat = key(trace_lint.lint_traces([tr]))
     assert vec == ref == bat
+
+
+def full_program_diagnostics(traces):
+    """Every row through ``lint_rules`` and extracted: the batched engine
+    as it was before it counted first."""
+    batch, _ = dram.batch_traces([(tr, 0) for tr in traces])
+    cmd = np.asarray(batch.cmd)
+    stacks = [np.asarray(x) for x in trace_lint._programs()[1](
+        batch.cmd, batch.bank, batch.dt)]
+    return [d for ti in range(len(traces))
+            for d in trace_lint._extract(*(x[ti] for x in stacks), cmd[ti],
+                                         ti)]
+
+
+def _seeded(*rule_ids):
+    return [raw_trace(SEEDED[r][0]) for r in rule_ids]
+
+
+def parity_window(name):
+    clean = [idd_loops.idd2n(reps=2)]
+    late_ref = raw_trace([(NOP, 0, T.tREFI + trace_lint.REFI_SLACK + 10),
+                          (REF, 0, 1)])
+    return {
+        "mixed": _seeded("tRCD") + clean * 3 + _seeded("tFAW") + clean * 3
+        + _seeded("REF_BANK_OPEN"),
+        "warning-only": clean + [late_ref] + clean,
+        "all-clean": clean * 5,
+        "all-fire": _seeded(*sorted(SEEDED)),
+        "five-of-nine-fire": _seeded("tRP", "tWR") + clean * 2
+        + _seeded("tXS") + clean * 2 + _seeded("DT_NEGATIVE", "tCCD"),
+    }[name]
+
+
+@pytest.mark.parametrize("window", ["mixed", "warning-only", "all-clean",
+                                    "all-fire", "five-of-nine-fire"])
+def test_counting_engine_returns_the_full_programs_diagnostics(window):
+    """Counting first and re-running only the traces that fired returns
+    the very list the full program over every row gives, and the
+    reference walk's per trace; ``fired_traces`` counts the traces with
+    any diagnostic."""
+    traces = parity_window(window)
+    t0 = time.perf_counter()
+    diags = trace_lint.lint_traces(traces)
+    (fetch,) = [r for r in RECORDER.inside(t0, time.perf_counter())
+                if r.name == "lint.fetch"]
+    assert diags == full_program_diagnostics(traces)
+    assert diags == [d for i, tr in enumerate(traces)
+                     for d in trace_lint.reference_lint(tr, i)]
+    assert fetch.attrs["fired_traces"] == len({d.trace_index
+                                               for d in diags})
+    assert bool(diags) == (window != "all-clean")
 
 
 def test_batched_engine_reports_trace_index():

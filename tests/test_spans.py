@@ -128,7 +128,8 @@ def test_service_records_serve_spans_in_order(quick_vampire):
     # the lint's cmd, bank and dt planes (int32), padded to a power of 2
     assert by["lint.rules"].attrs["bytes"] == 3 * 3 * 4 * (
         1 << max(max(lengths) - 1, 1).bit_length())
-    assert by["lint.fetch"].attrs["bytes"] > 0
+    # nothing fired: only the three traces' int32 counts came down
+    assert by["lint.fetch"].attrs == {"bytes": 3 * 4, "fired_traces": 0}
     # the report: one float per (bucket slot, vendor) for each leaf
     assert by["service.slice"].attrs["bytes"] > 0
     assert by["service.slice"].attrs["bytes"] % (8 * 4) == 0
@@ -188,6 +189,26 @@ def test_program_spans_land_on_the_profilers_host_plane(tmp_path):
     assert {"lint.pack", "lint.rules", "lint.fetch", "lint.extract"} <= host
 
 
+def test_lint_records_every_span_whether_or_not_a_trace_fired():
+    from repro.analysis import trace_lint
+    from repro.core.dram import RD, make_trace
+    clean = idd_loops.validation_sweep(4)
+    bad = make_trace([RD], [0])                     # RD to a closed bank
+    for trs, fired in (([clean, clean], 0), ([clean, bad], 1)):
+        t0 = time.perf_counter()
+        diags = trace_lint.lint_traces(trs)
+        recs = [r for r in _since(t0) if r.name != COMPILE]
+        assert [r.name for r in recs] == ["lint.pack", "lint.rules",
+                                          "lint.fetch", "lint.extract"]
+        fetch = recs[2].attrs
+        assert fetch["fired_traces"] == fired == len(diags)
+        # the two int32 counts, then one fired row's planes up and its
+        # (R, N) bool mask, int32 deficits and int32 banks down
+        n = recs[1].attrs["bytes"] // (3 * 4 * 2)
+        assert fetch["bytes"] == 2 * 4 + fired * (
+            3 * 4 * n + len(trace_lint.RULES) * n * 9)
+
+
 # ---------------------------------------------------------------------------
 # stable names
 # ---------------------------------------------------------------------------
@@ -242,8 +263,9 @@ def test_baseline_kernels_carry_their_names(kind, surface):
 def test_lint_and_serve_programs_carry_their_names(quick_vampire):
     from repro.analysis import trace_lint
     z = jnp.zeros((8, 256), jnp.int32)
-    lowered = trace_lint._get_batch_kernel().lower(z, z, z)
-    assert "jit_lint_rules" in lowered.as_text()
+    lint_count, lint_rules = trace_lint._programs()
+    assert "jit_lint_count" in lint_count.lower(z, z, z).as_text()
+    assert "jit_lint_rules" in lint_rules.lower(z, z, z).as_text()
     svc = EstimationService(quick_vampire, ServiceConfig())
     svc.submit_many([idd_loops.validation_sweep(1)])
     svc.drain()
