@@ -21,7 +21,8 @@ jaxpr and lowers it to StableHLO text (the same artifact
   jit cache of the shared batched dispatchers (``_cache_size`` growth
   probes, generalizing the PR 3 regression test into a pass); the
   serving stack gets its own probe (:func:`audit_serving`) asserting the
-  ring's pad-shape bucketing bounds the engine's compiled-program count.
+  ring's pad-shape bucketing bounds the engine's compiled-program count,
+  traces cut into ring rows with their carries included.
 
 Findings are structured (:class:`AuditFinding`); ``python -m
 repro.analysis`` fails the CI gate on any ERROR severity.
@@ -60,7 +61,7 @@ class AuditFinding:
     impl: str       # registry impl name
     mode: str       # estimate mode
     check: str      # 'float64' | 'host_callback' | 'pad_masking' |
-                    # 'recompile' | 'audit_trace'
+                    # 'recompile' | 'audit_trace' | 'lint'
     severity: str   # 'error' | 'warning'
     detail: str
 
@@ -324,6 +325,91 @@ def audit_serving(model, impl: str = "vectorized") -> list[AuditFinding]:
             kind, impl, "mean", "recompile", ERROR,
             "a mixed-length window landing in an already-compiled bucket "
             "recompiled the serving dispatch"))
+    return findings + _audit_segmented(model, impl)
+
+
+def _audit_segmented(model, impl: str,
+                     segments: Sequence[int] = (2, 17, 64)
+                     ) -> list[AuditFinding]:
+    """Traces past the largest length bucket, cut into 2, 17 and 64 ring
+    rows with their carries, linted and served: the compiled serving and
+    lint programs stay within the bucket vocabulary (count buckets x
+    length buckets; for the lint, powers of two up to the largest count
+    and length buckets) and a second pass of the same traces compiles
+    nothing; the carried program holds the float32 contract and makes no
+    host callback."""
+    import jax
+
+    from repro.analysis import trace_lint
+    from repro.core import idd_loops
+    from repro.core.dram import CommandTrace
+    from repro.serving import EstimationService, RingConfig, ServiceConfig
+
+    kind = model.kind
+    findings: list[AuditFinding] = []
+    ring = RingConfig(length_buckets=(64, 128), count_buckets=(4, 8, 32, 64))
+    svc = EstimationService(model, ServiceConfig(ring=ring, impl=impl))
+    unit = idd_loops.idd0(reps=4)
+    reps = -(-max(segments) * ring.max_length // int(unit.n))
+    stream = CommandTrace(*(np.concatenate([np.asarray(x)] * reps)
+                            for x in unit))
+    trs = [CommandTrace(*(x[:k * ring.max_length - 7] for x in stream))
+           for k in segments]
+    lint_count = trace_lint._programs()[0]
+
+    def run():
+        tickets, rejections = svc.submit_many(trs)
+        svc.drain()
+        for t in tickets:
+            if t is not None:
+                svc.result(t)
+        return (svc.engine.cache_size(), lint_count._cache_size(),
+                rejections)
+
+    lint_before = lint_count._cache_size()
+    first, lint_first, rejections = run()
+    if rejections:
+        findings.append(AuditFinding(
+            kind, impl, "mean", "lint", ERROR,
+            f"the lint rejected {len(rejections)} legal segmented traces: "
+            f"{[r.rules for r in rejections]}"))
+    vocabulary = len(ring.count_buckets) * len(ring.length_buckets)
+    lint_vocabulary = (ring.max_batch.bit_length()
+                       * (ring.max_length - 1).bit_length())
+    if first > vocabulary:
+        findings.append(AuditFinding(
+            kind, impl, "mean", "recompile", ERROR,
+            f"segmented traces compiled {first} serving programs, past "
+            f"the {vocabulary} of the bucket vocabulary"))
+    if lint_first - lint_before > lint_vocabulary:
+        findings.append(AuditFinding(
+            kind, impl, "mean", "recompile", ERROR,
+            f"segmented traces compiled {lint_first - lint_before} lint "
+            f"programs, past the {lint_vocabulary} of the vocabulary"))
+    if run()[:2] != (first, lint_first):
+        findings.append(AuditFinding(
+            kind, impl, "mean", "recompile", ERROR,
+            "a second pass of the same segmented traces recompiled the "
+            "serving or the lint dispatch"))
+
+    svc.submit_many(trs[-1:])
+    rb = svc.ring.take()
+    fn = svc.engine._dispatch_fn(None, False)
+    args = (svc.engine.resident, rb.batch.trace, rb.batch.weight,
+            rb.batch.carry)
+    prims = _primitive_names(jax.make_jaxpr(fn)(*args).jaxpr)
+    hits = sorted(p for p in prims
+                  if any(m in p for m in _CALLBACK_MARKERS))
+    if hits:
+        findings.append(AuditFinding(
+            kind, impl, "mean", "host_callback", ERROR,
+            f"host-callback primitives in the carried dispatch: {hits}"))
+    m = _F64_RE.search(fn.lower(*args).as_text())
+    if m:
+        findings.append(AuditFinding(
+            kind, impl, "mean", "float64", ERROR,
+            f"the carried dispatch lowers {m.group(0)} buffers (float32 "
+            f"contract violated)"))
     return findings
 
 

@@ -129,12 +129,12 @@ class _NumpyBackend:
         return np
 
     @staticmethod
-    def exclusive_cummax(x):
+    def exclusive_cummax(x, seed=NEG):
         c = np.maximum.accumulate(x, axis=0)
         out = np.empty_like(c)
         out[:1] = NEG
         out[1:] = c[:-1]
-        return out
+        return np.maximum(out, seed)
 
     @staticmethod
     def scatter_times(size: int, slot, times):
@@ -155,12 +155,12 @@ class _JaxBackend:
         return jnp
 
     @staticmethod
-    def exclusive_cummax(x):
+    def exclusive_cummax(x, seed=NEG):
         import jax
         import jax.numpy as jnp
         c = jax.lax.cummax(x, axis=0)
-        return jnp.concatenate(
-            [jnp.full_like(c[:1], NEG), c[:-1]], axis=0)
+        return jnp.maximum(jnp.concatenate(
+            [jnp.full_like(c[:1], NEG), c[:-1]], axis=0), seed)
 
     @staticmethod
     def scatter_times(size: int, slot, times):
@@ -170,15 +170,81 @@ class _JaxBackend:
 
 
 # ---------------------------------------------------------------------------
+# Segment carry: what a segment of a longer trace inherits from before it.
+# One int32 vector per segment, read by _Ctx as the seed of every "last
+# event" table.  Times are the segment's own, 0 at its first command, so
+# no table ever holds whole-trace time: an event 2^30 or more cycles
+# before the segment is clamped to NEG, which no interface timing can
+# reach.  empty_carry() (nothing before) is the identity.
+# ---------------------------------------------------------------------------
+C_START = 0                        # the trace's first command (<= 0)
+C_ACT_B = slice(1, 9)              # per bank: last ACT time
+C_CLOSE_B = slice(9, 17)           # last PRE/PREA time
+C_WR_B = slice(17, 25)             # last WR time
+C_RD_B = slice(25, 33)             # last RD time
+C_OPEN_B = slice(33, 41)           # bank open (0/1)
+C_REF, C_PDX, C_SRX, C_PDX_SLOW = 41, 42, 43, 44   # last times
+C_IN_PDN, C_IN_SR, C_SLOW_ENTRY = 45, 46, 47        # flags (0/1)
+C_ACT4 = slice(48, 52)             # the last four ACT times, oldest first
+CARRY_WIDTH = 52
+
+
+def empty_carry() -> np.ndarray:
+    """The carry of a trace's first segment (and of a whole trace)."""
+    c = np.full(CARRY_WIDTH, NEG, np.int32)
+    c[C_START] = 0
+    c[C_OPEN_B] = 0
+    c[[C_IN_PDN, C_IN_SR, C_SLOW_ENTRY]] = 0
+    return c
+
+
+def lint_carry(dt, starts, ev) -> np.ndarray:
+    """The (k, CARRY_WIDTH) int32 carries of the segments of one trace
+    that begin at ``starts``, from the trace's cycles ``dt`` and its
+    ``energy_model.events_before`` at those starts ``ev`` (host numpy).
+    Times are in cycles from each segment's first command, clamped at
+    NEG."""
+    dt = np.asarray(dt, np.int64)
+    t = np.cumsum(dt) - dt
+    t0 = t[np.asarray(starts, np.int64)]
+
+    def time_of(i):
+        i = np.asarray(i)
+        rel = t[np.maximum(i, 0)] - t0.reshape((-1,) + (1,) * (i.ndim - 1))
+        return np.where(i >= 0, np.maximum(rel, NEG), NEG)
+
+    out = np.tile(empty_carry(), (len(t0), 1))
+    out[:, C_START] = np.maximum(-t0, NEG)
+    out[:, C_ACT_B] = time_of(ev.act)
+    out[:, C_CLOSE_B] = time_of(ev.close)
+    out[:, C_WR_B] = time_of(ev.wr)
+    out[:, C_RD_B] = time_of(ev.rd)
+    out[:, C_OPEN_B] = ev.act > ev.close
+    out[:, C_REF] = time_of(ev.ref)
+    out[:, C_PDX] = time_of(ev.pdx)
+    out[:, C_SRX] = time_of(ev.srx)
+    out[:, C_PDX_SLOW] = time_of(ev.pdx_slow)
+    out[:, C_IN_PDN] = np.maximum(ev.pde, ev.pde_slow) > ev.pdx
+    out[:, C_IN_SR] = ev.sre > ev.srx
+    out[:, C_SLOW_ENTRY] = ev.pde_slow > ev.pde
+    out[:, C_ACT4] = time_of(ev.act4)
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Context: every derived table the rules read, built in one vectorized pass
 # ---------------------------------------------------------------------------
 class _Ctx:
-    """Per-trace rule-evaluation context (plain attribute bag)."""
+    """Per-trace rule-evaluation context (plain attribute bag).  ``carry``
+    (a CARRY_WIDTH vector, None for a whole trace) seeds every table with
+    the segment's past, in the segment's own time."""
 
-    def __init__(self, cmd, bank, dt, backend):
+    def __init__(self, cmd, bank, dt, carry, backend):
         xp = backend.xp()
         self.xp = xp
         self.T = TIMING
+        if carry is None:
+            carry = empty_carry()
         n = cmd.shape[0]
         self.n = n
         self.cmd = cmd
@@ -186,6 +252,7 @@ class _Ctx:
         self.dt = dt
         idx = xp.arange(n)
         self.t = xp.cumsum(dt, axis=0) - dt           # issue time of slot i
+        self.t_start = carry[C_START]                 # the trace's start
 
         self.is_act = cmd == ACT
         self.is_pre = cmd == PRE
@@ -203,38 +270,43 @@ class _Ctx:
         rd_b = self.is_rd[:, None] & onehot
         self.close_b = close_b
 
-        def last_t(ev):
-            return backend.exclusive_cummax(xp.where(ev, self.t, NEG))
+        def last_t(ev, seed=NEG):
+            return backend.exclusive_cummax(xp.where(ev, self.t, NEG), seed)
 
-        def last_t_b(ev_b):
+        def last_t_b(ev_b, seed):
             return backend.exclusive_cummax(
-                xp.where(ev_b, self.t[:, None], NEG))
+                xp.where(ev_b, self.t[:, None], NEG), seed)
 
-        def last_i(ev):
-            return backend.exclusive_cummax(xp.where(ev, idx, -1))
+        # indices: a carried event sits at -1, before the segment's own
+        def last_i(ev, carried=False):
+            return backend.exclusive_cummax(xp.where(ev, idx, NEG),
+                                            xp.where(carried, -1, NEG))
 
-        def last_i_b(ev_b):
-            return backend.exclusive_cummax(xp.where(ev_b, idx[:, None], -1))
+        def last_i_b(ev_b, carried=False):
+            return backend.exclusive_cummax(xp.where(ev_b, idx[:, None], NEG),
+                                            xp.where(carried, -1, NEG))
 
         def own(tbl):
             return xp.take_along_axis(tbl, bank[:, None], axis=1)[:, 0]
 
         # per-bank last-event time tables (strictly before i) + own gathers
-        self.t_act_b = last_t_b(act_b)
-        self.t_wr_b = last_t_b(wr_b)
-        self.t_rd_b = last_t_b(rd_b)
+        self.t_act_b = last_t_b(act_b, carry[C_ACT_B])
+        self.t_wr_b = last_t_b(wr_b, carry[C_WR_B])
+        self.t_rd_b = last_t_b(rd_b, carry[C_RD_B])
         self.t_act_own = own(self.t_act_b)
-        self.t_close_own = own(last_t_b(close_b))
+        self.t_close_own = own(last_t_b(close_b, carry[C_CLOSE_B]))
 
         # bank open state before i: index-based so dt=0 ties keep order
-        self.open_b = last_i_b(act_b) > last_i_b(close_b)
+        self.open_b = (last_i_b(act_b, carry[C_OPEN_B] > 0)
+                       > last_i_b(close_b))
         self.open_own = own(self.open_b)
 
-        # any-bank scalars
-        self.t_act_any = last_t(self.is_act)
-        self.t_wr_any = last_t(self.is_wr)
-        self.t_rw_any = last_t(self.is_rw)
-        self.t_ref = last_t(self.is_ref)
+        # any-bank scalars (the carried ones are the latest of a bank's)
+        self.t_act_any = last_t(self.is_act, carry[C_ACT_B].max())
+        self.t_wr_any = last_t(self.is_wr, carry[C_WR_B].max())
+        self.t_rw_any = last_t(self.is_rw, xp.maximum(carry[C_WR_B].max(),
+                                                      carry[C_RD_B].max()))
+        self.t_ref = last_t(self.is_ref, carry[C_REF])
 
         # background-state machine (power-down / self-refresh)
         is_pde = cmd == PDE
@@ -242,20 +314,24 @@ class _Ctx:
         is_pdx = cmd == PDX
         is_sre = cmd == SRE
         is_srx = cmd == SRX
-        self.in_pdn = last_i(is_pde | is_pds) > last_i(is_pdx)
-        self.in_sr = last_i(is_sre) > last_i(is_srx)
-        self.t_pdx = last_t(is_pdx)
-        self.t_srx = last_t(is_srx)
+        self.in_pdn = (last_i(is_pde | is_pds, carry[C_IN_PDN] > 0)
+                       > last_i(is_pdx))
+        self.in_sr = last_i(is_sre, carry[C_IN_SR] > 0) > last_i(is_srx)
+        self.t_pdx = last_t(is_pdx, carry[C_PDX])
+        self.t_srx = last_t(is_srx, carry[C_SRX])
         # a PDX exiting a SLOW power-down needs the DLL relock (tXPDLL)
-        slow_entry = last_i(is_pds) > last_i(is_pde)
-        self.t_pdx_slow = last_t(is_pdx & slow_entry)
+        slow_entry = (last_i(is_pds, carry[C_SLOW_ENTRY] > 0)
+                      > last_i(is_pde))
+        self.t_pdx_slow = last_t(is_pdx & slow_entry, carry[C_PDX_SLOW])
 
-        # tFAW: time of the 4th-previous ACT (rolling four-activate window)
+        # tFAW: time of the 4th-previous ACT (rolling four-activate
+        # window), the carried four ACTs first
         k = xp.cumsum(self.is_act.astype(self.t.dtype), axis=0)
         slot = xp.where(self.is_act, k - 1, n)
-        act_times = backend.scatter_times(n + 1, slot, self.t)
-        gather = xp.where(self.is_act & (k >= 5), k - 5, n)
-        self.t_act_4ago = act_times[gather]
+        act_times = xp.concatenate([
+            carry[C_ACT4].astype(self.t.dtype),
+            backend.scatter_times(n + 1, slot, self.t)])
+        self.t_act_4ago = act_times[xp.where(self.is_act, k - 1, n + 4)]
 
 
 # ---------------------------------------------------------------------------
@@ -399,8 +475,7 @@ def _r_dt(c):
 @rule("tREFI", "refresh arrived past the tREFI deadline (plus scheduling "
                "slack)", severity=WARNING)
 def _r_trefi(c):
-    anchor = c.xp.maximum(c.xp.maximum(c.t_ref, c.t_srx),
-                          c.xp.zeros_like(c.t))
+    anchor = c.xp.maximum(c.xp.maximum(c.t_ref, c.t_srx), c.t_start)
     deadline = anchor + c.T.tREFI + REFI_SLACK
     deficit = c.t - deadline
     return c.is_ref & (deficit > 0), deficit, c.bank
@@ -412,9 +487,9 @@ _RULE_ORDER: tuple[str, ...] = tuple(RULES)
 # ---------------------------------------------------------------------------
 # Engines
 # ---------------------------------------------------------------------------
-def _eval_rules(cmd, bank, dt, backend):
+def _eval_rules(cmd, bank, dt, carry, backend):
     """(R, n) stacked (mask, deficit, bank) over every registered rule."""
-    ctx = _Ctx(cmd, bank, dt, backend)
+    ctx = _Ctx(cmd, bank, dt, carry, backend)
     xp = ctx.xp
     masks, deficits, banks = [], [], []
     for rid in _RULE_ORDER:
@@ -425,15 +500,19 @@ def _eval_rules(cmd, bank, dt, backend):
     return xp.stack(masks), xp.stack(deficits), xp.stack(banks)
 
 
-def _extract(mask, deficit, bank, cmd, trace_index: int) -> list[Diagnostic]:
+def _extract(mask, deficit, bank, cmd, trace_index: int,
+             base: int = 0) -> list[Diagnostic]:
+    """Diagnostics of one row's stacks; ``base`` is the row's first
+    command's index in its whole trace."""
     out = []
     rule_rows, cmd_idx = np.nonzero(mask)
-    for r, i in zip(rule_rows.tolist(), cmd_idx.tolist()):
+    for r, j in zip(rule_rows.tolist(), cmd_idx.tolist()):
+        i = base + j
         rid = _RULE_ORDER[r]
-        margin = int(deficit[r, i])
-        b = int(bank[r, i])
+        margin = int(deficit[r, j])
+        b = int(bank[r, j])
         out.append(Diagnostic(rid, RULES[rid].severity, trace_index, i, b,
-                              margin, _message(rid, int(cmd[i]), i, b,
+                              margin, _message(rid, int(cmd[j]), i, b,
                                                margin)))
     out.sort(key=lambda d: (d.trace_index, d.cmd_index,
                             _RULE_ORDER.index(d.rule)))
@@ -445,41 +524,59 @@ def lint_trace(trace: CommandTrace, trace_index: int = 0) -> list[Diagnostic]:
     cmd = np.asarray(trace.cmd, dtype=np.int64)
     bank = np.asarray(trace.bank, dtype=np.int64)
     dt = np.asarray(trace.dt, dtype=np.int64)
-    mask, deficit, bank_r = _eval_rules(cmd, bank, dt, _NumpyBackend)
+    mask, deficit, bank_r = _eval_rules(cmd, bank, dt, None, _NumpyBackend)
     return _extract(mask, deficit, bank_r, cmd, trace_index)
+
+
+@functools.cache
+def _empty_plane(rows: int):
+    """The (rows, CARRY_WIDTH) carries of a batch of whole traces, kept on
+    the device: sent once, not with every batch."""
+    import jax
+    return jax.device_put(np.tile(empty_carry(), (rows, 1)))
 
 
 @functools.cache
 def _programs():
     """``(lint_count, lint_rules)``, the two jitted (T, N) batch programs,
     built lazily (keeps numpy-only callers of :func:`lint_trace` free of
-    any jax dispatch).  ``lint_count`` gives each trace's number of fired
-    (rule, command) cells, (T,) int32: XLA fuses the masks into the sum
-    and drops the deficit and bank stacks.  ``lint_rules`` gives the
-    three (T, R, N) stacks (mask, deficit, bank)."""
+    any jax dispatch).  Both read the rows' (T, CARRY_WIDTH) carries.
+    ``lint_count`` gives each row's number of fired (rule, command)
+    cells, (T,) int32: XLA fuses the masks into the sum and drops the
+    deficit and bank stacks.  ``lint_rules`` gives the three (T, R, N)
+    stacks (mask, deficit, bank)."""
     import jax
 
-    def stacks(cmd, bank, dt):
-        return jax.vmap(lambda c, b, d: _eval_rules(c, b, d, _JaxBackend))(
-            cmd, bank, dt)
+    def stacks(cmd, bank, dt, carry):
+        return jax.vmap(
+            lambda c, b, d, k: _eval_rules(c, b, d, k, _JaxBackend))(
+                cmd, bank, dt, carry)
 
     @jax.jit
-    def lint_count(cmd, bank, dt):
-        return stacks(cmd, bank, dt)[0].sum(axis=(1, 2), dtype="int32")
+    def lint_count(cmd, bank, dt, carry):
+        return stacks(cmd, bank, dt, carry)[0].sum(axis=(1, 2),
+                                                   dtype="int32")
 
     @jax.jit
-    def lint_rules(cmd, bank, dt):
-        return stacks(cmd, bank, dt)
+    def lint_rules(cmd, bank, dt, carry):
+        return stacks(cmd, bank, dt, carry)
 
     return lint_count, lint_rules
 
 
-def lint_arrays_batched(cmd, bank, dt) -> list[Diagnostic]:
+def lint_arrays_batched(cmd, bank, dt, carry=None, owner=None,
+                        base=None) -> list[Diagnostic]:
     """Lint a padded (T, N) command batch: count on the device, fetch what
     fired.
 
+    ``carry`` is the rows' (T, CARRY_WIDTH) segment carries (None: every
+    row a whole trace); a diagnostic of row ``r`` names trace
+    ``owner[r]`` and command ``base[r]`` + its index in the row (default:
+    row ``r``, from 0), so a trace cut into rows gets its diagnostics in
+    whole-trace indices.
+
     ``lint_count`` runs over every row and only its (T,) counts come to
-    the host.  The traces that fired, and only they, run again through
+    the host.  The rows that fired, and only they, run again through
     ``lint_rules``, whose (k, R, N) stacks are extracted; k is padded to a
     power of two (at most T) with NOP/dt=0 rows, inert under every rule,
     so compiled shapes stay bounded.  The diagnostics are exactly those
@@ -495,10 +592,15 @@ def lint_arrays_batched(cmd, bank, dt) -> list[Diagnostic]:
     diagnostics), each recorded on every call."""
     import jax
     lint_count, lint_rules = _programs()
+    rows = np.shape(cmd)[0]
+    if carry is None:
+        carry = np.tile(empty_carry(), (rows, 1))
+    owner = np.arange(rows) if owner is None else owner
+    base = np.zeros(rows, np.int64) if base is None else base
     with span("lint.rules") as s:
-        s.attrs["bytes"] = sum(x.nbytes for x in (cmd, bank, dt)
+        s.attrs["bytes"] = sum(x.nbytes for x in (cmd, bank, dt, carry)
                                if isinstance(x, np.ndarray))
-        counts = jax.block_until_ready(lint_count(cmd, bank, dt))
+        counts = jax.block_until_ready(lint_count(cmd, bank, dt, carry))
     with span("lint.fetch") as s:
         counts = np.asarray(counts)
         fired = np.flatnonzero(counts)
@@ -508,14 +610,15 @@ def lint_arrays_batched(cmd, bank, dt) -> list[Diagnostic]:
             pad = min(1 << (len(fired) - 1).bit_length(), len(counts)) \
                 - len(fired)
             planes = [np.pad(np.asarray(x)[fired], ((0, pad), (0, 0)))
-                      for x in (cmd, bank, dt)]
+                      for x in (cmd, bank, dt, carry)]
             found = [np.asarray(x) for x in lint_rules(*planes)]
             s.attrs["bytes"] += sum(x.nbytes for x in planes + found)
     with span("lint.extract"):
         out = []
-        for k, ti in enumerate(fired.tolist()):
+        for k, r in enumerate(fired.tolist()):
             mask, deficit, bank_r = (x[k] for x in found)
-            out.extend(_extract(mask, deficit, bank_r, planes[0][k], ti))
+            out.extend(_extract(mask, deficit, bank_r, planes[0][k],
+                                int(owner[r]), int(base[r])))
     return out
 
 
@@ -526,31 +629,63 @@ def lint_batch(tb) -> list[Diagnostic]:
     return lint_arrays_batched(tb.trace.cmd, tb.trace.bank, tb.trace.dt)
 
 
-def lint_traces(traces: Sequence[CommandTrace]) -> list[Diagnostic]:
+def lint_traces(traces: Sequence) -> list[Diagnostic]:
     """Lint a sequence of ragged traces through the batched engine
     (:func:`lint_arrays_batched`: count on the device, full diagnostics
     for the traces that fired, the same list as one full pass), padding
-    to the next power of two so repeated calls share compiled shapes.
+    the rows and their length to the next power of two so repeated calls
+    share compiled shapes.
+
+    Each item is a whole trace (a ``CommandTrace``: one row, nothing
+    before it) or a trace cut into rows (``serving.ring.Segments``: its
+    ``trace``, ascending row ``bounds`` from 0 to its length, and each
+    row's ``lint_carry`` of the commands before it; one row lints as a
+    whole trace).  A cut trace's
+    diagnostics are the whole trace's, in whole-trace command indices,
+    and no row needs a length past its own.  Each row is timed from its
+    first command, so a row must span fewer than 2^30 cycles; a cut
+    trace may span any number.
 
     Only the three fields the rules read are padded (host-side, one
     allocation each): the NOP/dt=0 pad rows are inert under every rule, so
     no per-trace :func:`~repro.core.dram.pad_trace` round-trip (which
-    would also ship the untouched data payload) is needed."""
-    traces = list(traces)
+    would also copy row/col/data) is needed."""
     if not traces:
         return []
     with span("lint.pack"):
-        longest = max(int(tr.n) for tr in traces)
+        rows, carries = [], []
+        for i, item in enumerate(traces):
+            if isinstance(item, CommandTrace) or len(item.bounds) == 2:
+                tr = item if isinstance(item, CommandTrace) else item.trace
+                item_rows = [(tr, 0, int(tr.n))]
+                carries.append(None)
+            else:
+                b = [int(x) for x in item.bounds]
+                item_rows = [(item.trace, lo, hi)
+                             for lo, hi in zip(b[:-1], b[1:])]
+                carries.append(item.lint_carry)
+            rows += [(i, tr, lo, hi) for tr, lo, hi in item_rows]
+        count = 1 << (len(rows) - 1).bit_length()
+        longest = max(hi - lo for _, _, lo, hi in rows)
         length = 1 << max(longest - 1, 1).bit_length()
-        cmd = np.zeros((len(traces), length), np.int32)   # NOP == 0
-        bank = np.zeros((len(traces), length), np.int32)
-        dt = np.zeros((len(traces), length), np.int32)
-        for i, tr in enumerate(traces):
-            n = int(tr.n)
-            cmd[i, :n] = np.asarray(tr.cmd)
-            bank[i, :n] = np.asarray(tr.bank)
-            dt[i, :n] = np.asarray(tr.dt)
-    return lint_arrays_batched(cmd, bank, dt)
+        cmd = np.zeros((count, length), np.int32)   # NOP == 0
+        bank = np.zeros((count, length), np.int32)
+        dt = np.zeros((count, length), np.int32)
+        for r, (_, tr, lo, hi) in enumerate(rows):
+            cmd[r, :hi - lo] = np.asarray(tr.cmd)[lo:hi]
+            bank[r, :hi - lo] = np.asarray(tr.bank)[lo:hi]
+            dt[r, :hi - lo] = np.asarray(tr.dt)[lo:hi]
+        if any(c is not None for c in carries):
+            carry = np.tile(empty_carry(), (count, 1))
+            carry[:len(rows)] = np.concatenate(
+                [empty_carry()[None] if c is None else c for c in carries])
+        else:
+            carry = _empty_plane(count)
+        owner = np.zeros(count, np.int64)
+        owner[:len(rows)] = [i for i, _, _, _ in rows]
+        base = np.zeros(count, np.int64)
+        base[:len(rows)] = [lo for _, _, lo, _ in rows]
+    return lint_arrays_batched(cmd, bank, dt, carry, owner, base)
 
 
 # ---------------------------------------------------------------------------

@@ -105,9 +105,11 @@ def _act_pair_charge(ds) -> jax.Array:
 
 
 def micron_charges(trace: CommandTrace, open_banks, bg_state,
-                   ds) -> jax.Array:
+                   ds, carried_act=False) -> jax.Array:
     """Per-command charge (mA*cycles) of the TN-41-01-style estimate.
-    ``ds`` maps IDD key -> current; values broadcast against the trace."""
+    ``ds`` maps IDD key -> current; values broadcast against the trace.
+    ``carried_act``: a segment's carry says the whole trace issues an
+    ACT (``SegmentCarry.any_act``)."""
     del open_banks  # the calculator's documented flaw: bank count ignored
     dt = trace.dt.astype(jnp.float32)
     # Worst-case background: all-banks-active current whenever not in a
@@ -119,7 +121,7 @@ def micron_charges(trace: CommandTrace, open_banks, bg_state,
     # actual command spacing in the trace ([26]'s "does not account for any
     # additional time that may elapse between two DRAM commands").
     q_act = _act_pair_charge(ds)
-    any_act = jnp.any(trace.cmd == ACT)
+    any_act = jnp.any(trace.cmd == ACT) | carried_act
     charge = charge + jnp.where((bg_state == BG_ACTIVE) & any_act,
                                 q_act * dt / _T.tRC, 0.0)
     # Read/write power stacked on the (already worst-case) background — the
@@ -134,9 +136,10 @@ def micron_charges(trace: CommandTrace, open_banks, bg_state,
 
 
 def drampower_charges(trace: CommandTrace, open_banks, bg_state,
-                      ds) -> jax.Array:
+                      ds, carried_act=False) -> jax.Array:
     """Per-command charge (mA*cycles) of the DRAMPower-style estimate:
-    datasheet IDDs, actual timing."""
+    datasheet IDDs, actual timing (``carried_act`` is unused: its ACT
+    charge follows the trace's own ACTs)."""
     dt = trace.dt.astype(jnp.float32)
     # Bank-sensitive background (DRAMPower includes the [65, 107] extension:
     # linear interpolation between IDD2N and IDD3N by open-bank count), but
@@ -159,20 +162,28 @@ def drampower_charges(trace: CommandTrace, open_banks, bg_state,
 _CHARGE_FNS = {"micron": micron_charges, "drampower": drampower_charges}
 
 
-def micron_power(trace: CommandTrace, ds: dict[str, float]) -> EnergyReport:
-    """TN-41-01-style estimate from datasheet IDDs (single trace)."""
+def _carried_act(carry) -> jax.Array | bool:
+    return False if carry is None else carry.any_act
+
+
+def micron_power(trace: CommandTrace, ds: dict[str, float],
+                 carry=None) -> EnergyReport:
+    """TN-41-01-style estimate from datasheet IDDs (single trace, or one
+    segment of one with its ``carry``)."""
     ds = with_lowpower_defaults(ds)
-    ob, pd = _bg_state(extract_structural_features(trace))
+    ob, pd = _bg_state(extract_structural_features(trace, carry=carry))
     charge = micron_charges(trace, ob, pd,
-                            {k: jnp.float32(ds[k]) for k in BASELINE_IDD_KEYS})
+                            {k: jnp.float32(ds[k]) for k in BASELINE_IDD_KEYS},
+                            _carried_act(carry))
     return _report(jnp.sum(charge), trace.total_cycles())
 
 
-def drampower(trace: CommandTrace, ds: dict[str, float]) -> EnergyReport:
+def drampower(trace: CommandTrace, ds: dict[str, float],
+              carry=None) -> EnergyReport:
     """DRAMPower-style estimate: datasheet IDDs, actual timing (single
-    trace)."""
+    trace, or one segment of one with its ``carry``)."""
     ds = with_lowpower_defaults(ds)
-    ob, pd = _bg_state(extract_structural_features(trace))
+    ob, pd = _bg_state(extract_structural_features(trace, carry=carry))
     charge = drampower_charges(
         trace, ob, pd, {k: jnp.float32(ds[k]) for k in BASELINE_IDD_KEYS})
     return _report(jnp.sum(charge), trace.total_cycles())
@@ -187,21 +198,23 @@ MODELS = {"micron": micron_power, "drampower": drampower}
 def _batched_baseline(charge_fn):
     @jax.jit
     def dispatch(trace: CommandTrace, weight: jax.Array,
-                 table: jax.Array) -> EnergyReport:
+                 table: jax.Array, carry=None) -> EnergyReport:
         """Energy reports of every (trace, vendor) pair in one dispatch.
         ``trace``/``weight`` are a TraceBatch's padded fields; ``table`` is
-        the stacked (vendors, len(BASELINE_IDD_KEYS)) datasheet matrix."""
-        def one_trace(tr: CommandTrace, w: jax.Array):
-            ob, pd = _bg_state(extract_structural_features(tr))
+        the stacked (vendors, len(BASELINE_IDD_KEYS)) datasheet matrix;
+        ``carry`` the rows' segment carries (None for whole traces)."""
+        def one_trace(tr: CommandTrace, w: jax.Array, c):
+            ob, pd = _bg_state(extract_structural_features(tr, carry=c))
             cycles = jnp.sum(tr.dt * w.astype(jnp.int32), dtype=jnp.int32)
 
             def one_vendor(row):
                 ds = {k: row[i] for i, k in enumerate(BASELINE_IDD_KEYS)}
-                return jnp.sum(charge_fn(tr, ob, pd, ds) * w)
+                return jnp.sum(charge_fn(tr, ob, pd, ds, _carried_act(c))
+                               * w)
 
             return jax.vmap(one_vendor)(table), cycles
 
-        charge, cycles = jax.vmap(one_trace)(trace, weight)   # (T, V), (T,)
+        charge, cycles = jax.vmap(one_trace)(trace, weight, carry)
         return _report(charge,
                        jnp.broadcast_to(cycles[:, None], charge.shape))
     return dispatch
@@ -213,7 +226,7 @@ _BATCHED = {kind: _batched_baseline(fn) for kind, fn in _CHARGE_FNS.items()}
 def _batched_baseline_surface(charge_fn):
     @jax.jit
     def dispatch(trace: CommandTrace, weight: jax.Array,
-                 table: jax.Array) -> EnergyReport:
+                 table: jax.Array, carry=None) -> EnergyReport:
         """``mode='surface'`` twin of the mean dispatch: the identical
         per-command charges grouped onto the (bank, row-band) cells ->
         (traces, vendors, banks, row_bands)-shaped report leaves.  The
@@ -221,16 +234,17 @@ def _batched_baseline_surface(charge_fn):
         point — so their surfaces are flat in everything but workload
         placement; the decomposition is what exposes that flatness next
         to VAMPIRE's."""
-        def one_trace(tr: CommandTrace, w: jax.Array):
-            ob, pd = _bg_state(extract_structural_features(tr))
+        def one_trace(tr: CommandTrace, w: jax.Array, c):
+            ob, pd = _bg_state(extract_structural_features(tr, carry=c))
 
             def one_vendor(row):
                 ds = {k: row[i] for i, k in enumerate(BASELINE_IDD_KEYS)}
-                return surface_charge(tr, w, charge_fn(tr, ob, pd, ds))
+                return surface_charge(
+                    tr, w, charge_fn(tr, ob, pd, ds, _carried_act(c)))
 
             return jax.vmap(one_vendor)(table), surface_cycles(tr, w)
 
-        charge, cycles = jax.vmap(one_trace)(trace, weight)
+        charge, cycles = jax.vmap(one_trace)(trace, weight, carry)
         return _report(charge,
                        jnp.broadcast_to(cycles[:, None], charge.shape))
     return dispatch
@@ -323,22 +337,24 @@ class DatasheetModel(model_api.StackedEstimatorMixin):
         if mode == "surface":
             if impl == "vectorized":
                 return _BATCHED_SURFACE[self.kind](tb.trace, tb.weight,
-                                                   self._table_for(idx))
+                                                   self._table_for(idx),
+                                                   tb.carry)
             if impl == "pallas":
                 from repro.kernels.baseline_energy import ops as bops
                 charge, cycles = bops.baseline_charge_matrix(
                     tb.trace, tb.weight, self._table_for(idx), self.kind,
-                    surface=True)
+                    surface=True, carry=tb.carry)
                 return _report(charge, jnp.broadcast_to(cycles[:, None],
                                                         charge.shape))
             return self._reference_surface(traces, tb, idx)
         if impl == "vectorized":
             rep = _BATCHED[self.kind](tb.trace, tb.weight,
-                                      self._table_for(idx))
+                                      self._table_for(idx), tb.carry)
         elif impl == "pallas":
             from repro.kernels.baseline_energy import ops as bops
             charge, cycles = bops.baseline_charge_matrix(
-                tb.trace, tb.weight, self._table_for(idx), self.kind)
+                tb.trace, tb.weight, self._table_for(idx), self.kind,
+                carry=tb.carry)
             rep = _report(charge,
                           jnp.broadcast_to(cycles[:, None], charge.shape))
         else:
@@ -351,20 +367,21 @@ class DatasheetModel(model_api.StackedEstimatorMixin):
         """``impl='reference'`` for ``mode='surface'``: the paper-figure
         per-trace charge formulas, grouped onto the (bank, row-band) cells
         one (trace, vendor) pair at a time."""
-        from repro.core.estimate_batch import original_traces
+        from repro.core.estimate_batch import original_traces, row_carries
         originals = original_traces(traces, tb)
         order = self.vendors
         charge_fn = _CHARGE_FNS[self.kind]
         per_trace = []
-        for tr in originals:
-            ob, pd = _bg_state(extract_structural_features(tr))
+        for tr, c in zip(originals, row_carries(tb)):
+            ob, pd = _bg_state(extract_structural_features(tr, carry=c))
             w = jnp.ones(tr.n, jnp.float32)
             pairs = []
             for j in idx:
                 ds = {k: jnp.float32(self.datasheets[order[j]][k])
                       for k in BASELINE_IDD_KEYS}
                 pairs.append(_report(
-                    surface_charge(tr, w, charge_fn(tr, ob, pd, ds)),
+                    surface_charge(tr, w, charge_fn(tr, ob, pd, ds,
+                                                    _carried_act(c))),
                     surface_cycles(tr, w)))
             per_trace.append(jax.tree_util.tree_map(
                 lambda *leaves: jnp.stack(leaves), *pairs))
@@ -374,15 +391,15 @@ class DatasheetModel(model_api.StackedEstimatorMixin):
     def _reference_matrix(self, traces, tb, idx) -> EnergyReport:
         """``impl='reference'``: the paper-figure per-trace functions
         (``micron_power``/``drampower``), one call per (trace, vendor)."""
-        from repro.core.estimate_batch import original_traces
+        from repro.core.estimate_batch import original_traces, row_carries
         originals = original_traces(traces, tb)
         order = self.vendors
         fn = MODELS[self.kind]
         per_trace = [
             jax.tree_util.tree_map(
                 lambda *leaves: jnp.stack(leaves),
-                *[fn(tr, self.datasheets[order[j]]) for j in idx])
-            for tr in originals]
+                *[fn(tr, self.datasheets[order[j]], c) for j in idx])
+            for tr, c in zip(originals, row_carries(tb))]
         return jax.tree_util.tree_map(lambda *rows: jnp.stack(rows),
                                       *per_trace)
 

@@ -196,16 +196,166 @@ class StructuralFeatures(NamedTuple):
     open_before: jax.Array   # (N, 8) bool: bank open state before each cmd
     bg_state: jax.Array      # (N,) int32 background-state code (BG_*)
     row_ones: jax.Array      # (N,) int32
+    has_prev: jax.Array      # (N,) bool: a RD/WR came before (carry included)
+
+
+# ---------------------------------------------------------------------------
+# Segment carry: a trace cut into rows keeps its whole-trace semantics
+# ---------------------------------------------------------------------------
+class SegmentCarry(NamedTuple):
+    """The state a trace segment inherits from the commands before it.
+
+    Every field is the last event of its kind before the segment's first
+    command, or a flag derived from such events, so the carries of all of
+    a trace's segments follow from the trace alone
+    (:func:`events_before`, :func:`segment_carry`) and need no estimate
+    of earlier segments.  ``any_act`` is the one whole-trace fact: the
+    Micron baseline bills ACT/PRE at the specification rate whenever the
+    trace issues any ACT.
+    The carry is parameter-independent.  :func:`empty_carry` (nothing
+    before) is the identity: every path then gives the unsegmented
+    answer.  Batched, every leaf has a leading row axis."""
+    open: jax.Array          # (8,) bool: bank open
+    bg_mode: jax.Array       # () int32 entry kind: BG_ACTIVE/_PDN_FAST/
+    #                          _PDN_SLOW/_SR (fast vs active from ``open``)
+    has_prev: jax.Array      # () bool: a RD/WR came before
+    prev_data: jax.Array     # (16,) uint32: its line (0 if none)
+    prev_bank: jax.Array     # () int32: its bank (-1 if none)
+    last_col: jax.Array      # (8,) int32: each bank's last RD/WR column
+    any_act: jax.Array       # () bool: the whole trace issues an ACT
+
+
+def empty_carry(rows: int | None = None) -> SegmentCarry:
+    """The carry of a trace's first segment (numpy leaves; with ``rows``
+    a leading row axis)."""
+    lead = () if rows is None else (rows,)
+    return SegmentCarry(
+        open=np.zeros(lead + (N_BANKS,), np.bool_),
+        bg_mode=np.full(lead, BG_ACTIVE, np.int32),
+        has_prev=np.zeros(lead, np.bool_),
+        prev_data=np.zeros(lead + (dram.LINE_WORDS,), np.uint32),
+        prev_bank=np.full(lead, -1, np.int32),
+        last_col=np.full(lead + (N_BANKS,), -1, np.int32),
+        any_act=np.zeros(lead, np.bool_))
+
+
+def last_before(pos: np.ndarray, at) -> np.ndarray:
+    """The last of the sorted event positions ``pos`` strictly before each
+    of ``at`` (-1 if none; ``at`` of any shape)."""
+    at = np.asarray(at)
+    if not len(pos):
+        return np.full(at.shape, -1)
+    j = np.searchsorted(pos, at) - 1
+    return np.where(j >= 0, pos[np.maximum(j, 0)], -1)
+
+
+def last_before_per_bank(pos: np.ndarray, bank: np.ndarray, at
+                         ) -> np.ndarray:
+    """:func:`last_before` per bank -> (len(at), 8): ``pos`` are events'
+    positions, ``bank`` the whole trace's bank field."""
+    of = bank[pos].astype(np.uint8)
+    by_bank = pos[np.argsort(of, kind="stable")]   # each bank's, in order
+    ends = np.cumsum(np.bincount(of, minlength=N_BANKS))
+    return np.stack([last_before(by_bank[e - c:e], at) for c, e in
+                     zip(np.bincount(of, minlength=N_BANKS), ends)], axis=1)
+
+
+class EventsBefore(NamedTuple):
+    """Per segment start, the position in the whole trace (-1: none) of
+    the last event of each kind strictly before it: what both the
+    integrator's :class:`SegmentCarry` and the lint's carry are made from
+    (:func:`segment_carry`, ``trace_lint.lint_carry``)."""
+    act: np.ndarray          # (k, 8) per bank: ACT
+    close: np.ndarray        # (k, 8) per bank: PRE, or PREA
+    rd: np.ndarray           # (k, 8) per bank: RD
+    wr: np.ndarray           # (k, 8) per bank: WR
+    ref: np.ndarray          # (k,)
+    pde: np.ndarray          # (k,) fast (or active) power-down entry
+    pde_slow: np.ndarray     # (k,)
+    pdx: np.ndarray          # (k,)
+    pdx_slow: np.ndarray     # (k,) a PDX whose entry was slow
+    sre: np.ndarray          # (k,)
+    srx: np.ndarray          # (k,)
+    act4: np.ndarray         # (k, 4) the last four ACTs, oldest first
+    any_act: np.ndarray      # (k,) bool: the whole trace issues an ACT
+
+
+def events_before(trace: CommandTrace, starts) -> EventsBefore:
+    """One scan of ``trace`` (host numpy): per event kind its sorted
+    positions, searched at every start."""
+    cmd = np.asarray(trace.cmd)
+    bank = np.asarray(trace.bank)
+    starts = np.asarray(starts, np.int64)
+    pos = {c: np.flatnonzero(cmd == c)
+           for c in (ACT, PRE, PREA, RD, WR, REF, PDE, PDE_SLOW, PDX, SRE,
+                     SRX)}
+
+    def last(c):
+        return last_before(pos[c], starts)
+
+    def per_bank(c):
+        return last_before_per_bank(pos[c], bank, starts)
+
+    # a PDX is slow when the power-down entry before it was
+    pdx = pos[PDX]
+    slow = last_before(pos[PDE_SLOW], pdx) > last_before(pos[PDE], pdx)
+    acts = np.concatenate([np.full(4, -1), pos[ACT]])
+    n_acts = np.searchsorted(pos[ACT], starts)
+    return EventsBefore(
+        act=per_bank(ACT),
+        close=np.maximum(per_bank(PRE), last(PREA)[:, None]),
+        rd=per_bank(RD), wr=per_bank(WR), ref=last(REF), pde=last(PDE),
+        pde_slow=last(PDE_SLOW), pdx=last(PDX),
+        pdx_slow=last_before(pdx[slow], starts), sre=last(SRE),
+        srx=last(SRX), act4=acts[n_acts[:, None] + np.arange(4)],
+        any_act=np.full(len(starts), len(pos[ACT]) > 0))
+
+
+def segment_carry(trace: CommandTrace, ev: EventsBefore) -> SegmentCarry:
+    """The integrator's carry of every segment of ``trace`` whose
+    :func:`events_before` is ``ev`` (host numpy, leading axis the
+    segments).  The background state follows the integrator's lattice
+    (self-refresh before power-down; the latest entry gives the
+    power-down kind)."""
+    bank = np.asarray(trace.bank)
+    out = empty_carry(len(ev.ref))._asdict()
+    out["open"][:] = ev.act > ev.close
+    rw_b = np.maximum(ev.rd, ev.wr)
+    out["last_col"][:] = np.where(
+        rw_b >= 0, np.asarray(trace.col)[np.maximum(rw_b, 0)], -1)
+    in_pdn = np.maximum(ev.pde, ev.pde_slow) > ev.pdx
+    out["bg_mode"][:] = np.where(
+        ev.sre > ev.srx, BG_SR,
+        np.where(in_pdn, np.where(ev.pde >= ev.pde_slow, BG_PDN_FAST,
+                                  BG_PDN_SLOW), BG_ACTIVE))
+    prev = rw_b.max(axis=1)
+    has = prev >= 0
+    out["has_prev"][:] = has
+    out["prev_data"][has] = np.asarray(trace.data)[prev[has]]
+    out["prev_bank"][has] = bank[prev[has]]
+    out["any_act"][:] = ev.any_act
+    return SegmentCarry(**out)
 
 
 # ---------------------------------------------------------------------------
 # Vectorized feature extraction
 # ---------------------------------------------------------------------------
-def _exclusive_cummax(x: jax.Array) -> jax.Array:
-    """cummax over axis 0, exclusive (state *before* each element)."""
+#: "no event before": the exclusive cummaxes below start from this, a
+#: carried event sits at index -1, the segment's own at 0..N-1
+_NONE = -2
+
+
+def _exclusive_cummax(x: jax.Array, seed=_NONE) -> jax.Array:
+    """cummax over axis 0, exclusive (state *before* each element),
+    starting from ``seed`` (broadcast against one element)."""
     shifted = jnp.concatenate(
-        [jnp.full_like(x[:1], -1), jax.lax.cummax(x, axis=0)[:-1]], axis=0)
-    return shifted
+        [jnp.full_like(x[:1], _NONE), jax.lax.cummax(x, axis=0)[:-1]], axis=0)
+    return jnp.maximum(shifted, seed)
+
+
+def _carried(flag) -> jax.Array:
+    """A carried event's seed: index -1 where ``flag`` holds."""
+    return jnp.where(flag, -1, _NONE)
 
 
 class StructuralState(NamedTuple):
@@ -223,7 +373,14 @@ class StructuralState(NamedTuple):
     has_prev: jax.Array     # (N,) bool
 
 
-def structural_state(trace: CommandTrace) -> StructuralState:
+def structural_state(trace: CommandTrace,
+                     carry: SegmentCarry | None = None) -> StructuralState:
+    """The index bookkeeping of one trace (or one segment of one, with the
+    ``carry`` of the commands before it; None is :func:`empty_carry`).
+    Every exclusive cummax starts from the carry: a carried event sits at
+    index -1, before the segment's own."""
+    if carry is None:
+        carry = empty_carry()
     cmd, bank = trace.cmd, trace.bank
     n = cmd.shape[0]
     idx = jnp.arange(n, dtype=jnp.int32)
@@ -235,8 +392,9 @@ def structural_state(trace: CommandTrace) -> StructuralState:
     bank_oh = jax.nn.one_hot(bank, N_BANKS, dtype=jnp.bool_)  # (N,8)
     act_ev = (cmd == ACT)[:, None] & bank_oh
     pre_ev = ((cmd == PRE)[:, None] & bank_oh) | (cmd == PREA)[:, None]
-    last_act = _exclusive_cummax(jnp.where(act_ev, idx[:, None], -1))  # (N,8)
-    last_pre = _exclusive_cummax(jnp.where(pre_ev, idx[:, None], -1))
+    last_act = _exclusive_cummax(jnp.where(act_ev, idx[:, None], _NONE),
+                                 _carried(carry.open))                 # (N,8)
+    last_pre = _exclusive_cummax(jnp.where(pre_ev, idx[:, None], _NONE))
     open_before = last_act > last_pre                                  # (N,8)
 
     # ---- background-state lattice (power-down / self-refresh) -------------
@@ -246,11 +404,16 @@ def structural_state(trace: CommandTrace) -> StructuralState:
     # bank open is ACTIVE power-down — PDE freezes (not closes) the banks,
     # and since ACT/PRE are illegal inside power-down the per-slot
     # ``open_before`` equals the open state at entry.
-    last_pdf = _exclusive_cummax(jnp.where(cmd == PDE, idx, -1))
-    last_pds = _exclusive_cummax(jnp.where(cmd == PDE_SLOW, idx, -1))
-    last_pdx = _exclusive_cummax(jnp.where(cmd == PDX, idx, -1))
-    last_sre = _exclusive_cummax(jnp.where(cmd == SRE, idx, -1))
-    last_srx = _exclusive_cummax(jnp.where(cmd == SRX, idx, -1))
+    def last_of(code, carried_mode=None):
+        seed = (_NONE if carried_mode is None
+                else _carried(carry.bg_mode == carried_mode))
+        return _exclusive_cummax(jnp.where(cmd == code, idx, _NONE), seed)
+
+    last_pdf = last_of(PDE, BG_PDN_FAST)
+    last_pds = last_of(PDE_SLOW, BG_PDN_SLOW)
+    last_pdx = last_of(PDX)
+    last_sre = last_of(SRE, BG_SR)
+    last_srx = last_of(SRX)
     in_pdn = jnp.maximum(last_pdf, last_pds) > last_pdx
     in_sr = last_sre > last_srx
     any_open = jnp.any(open_before, axis=1)
@@ -262,29 +425,37 @@ def structural_state(trace: CommandTrace) -> StructuralState:
                          ).astype(jnp.int32)
 
     # ---- previous RD/WR on the bus (for toggles & interleave mode) --------
-    prev_rw = _exclusive_cummax(jnp.where(is_rw, idx, -1))            # (N,)
-    has_prev = prev_rw >= 0
+    prev_rw = _exclusive_cummax(jnp.where(is_rw, idx, _NONE),
+                                _carried(carry.has_prev))             # (N,)
+    has_prev = prev_rw > _NONE
     prev_rw_c = jnp.maximum(prev_rw, 0)
-    prev_data = jnp.where(has_prev[:, None], trace.data[prev_rw_c],
-                          jnp.zeros_like(trace.data))                 # (N,16)
-    prev_bank = jnp.where(has_prev, bank[prev_rw_c], -1)
+    prev_data = jnp.where(
+        (prev_rw >= 0)[:, None], trace.data[prev_rw_c],
+        jnp.where((prev_rw == -1)[:, None], carry.prev_data, 0)
+    ).astype(trace.data.dtype)                                        # (N,16)
+    prev_bank = jnp.where(prev_rw >= 0, bank[prev_rw_c],
+                          jnp.where(prev_rw == -1, carry.prev_bank, -1))
 
     # last RD/WR column per bank, before each command
     rw_in_bank = is_rw[:, None] & bank_oh                             # (N,8)
-    last_rw_in_bank = _exclusive_cummax(jnp.where(rw_in_bank, idx[:, None], -1))
+    last_rw_in_bank = _exclusive_cummax(
+        jnp.where(rw_in_bank, idx[:, None], _NONE),
+        _carried(jnp.asarray(carry.last_col) >= 0))
     this_bank_last = jnp.take_along_axis(last_rw_in_bank, bank[:, None],
                                          axis=1)[:, 0]                # (N,)
-    has_bank_prev = this_bank_last >= 0
     prev_col_same_bank = jnp.where(
-        has_bank_prev, trace.col[jnp.maximum(this_bank_last, 0)], -1)
+        this_bank_last >= 0, trace.col[jnp.maximum(this_bank_last, 0)],
+        jnp.where(this_bank_last == -1,
+                  jnp.asarray(carry.last_col)[bank], -1))
 
+    # the previous RD/WR in this bank is the previous one on the bus when
+    # the banks match, so one column comparison serves both branches
     same_bank = has_prev & (prev_bank == bank)
-    same_col_prev = trace.col[prev_rw_c] == trace.col
-    same_col_in_bank = has_bank_prev & (prev_col_same_bank == trace.col)
+    same_col_in_bank = prev_col_same_bank == trace.col
     il_mode = jnp.where(
         ~has_prev, IL_NONE,
         jnp.where(same_bank,
-                  jnp.where(same_col_prev, IL_NONE, IL_COL),
+                  jnp.where(same_col_in_bank, IL_NONE, IL_COL),
                   jnp.where(same_col_in_bank, IL_BANK, IL_BANKCOL)))
     il_mode = il_mode.astype(jnp.int32)
 
@@ -294,18 +465,21 @@ def structural_state(trace: CommandTrace) -> StructuralState:
 
 
 def extract_structural_features(trace: CommandTrace,
-                                data_ops: DataOps = JNP_DATA_OPS
+                                data_ops: DataOps = JNP_DATA_OPS,
+                                carry: SegmentCarry | None = None
                                 ) -> StructuralFeatures:
     """The parameter-independent feature pass (see StructuralFeatures).
 
     ``data_ops`` injects the popcount/toggle reductions — pure jnp by
-    default, the Pallas kernel ops under the ``impl`` registry."""
-    st = structural_state(trace)
+    default, the Pallas kernel ops under the ``impl`` registry;
+    ``carry`` is the segment's (:func:`structural_state`)."""
+    st = structural_state(trace, carry)
     ones = data_ops.line_ones(trace.data)
     toggles = jnp.where(st.has_prev & st.is_rw,
                         data_ops.line_toggles(trace.data, st.prev_data), 0)
     return StructuralFeatures(st.is_rw, st.op, st.il_mode, ones, toggles,
-                              st.open_before, st.bg_state, st.row_ones)
+                              st.open_before, st.bg_state, st.row_ones,
+                              st.has_prev)
 
 
 def finalize_features(sf: StructuralFeatures,
@@ -330,12 +504,10 @@ def distribution_features(sf: StructuralFeatures, ones_frac,
     features with expected ones/toggle fractions. First-access semantics
     match ``extract_structural_features``: the first RD/WR on the bus has no
     previous burst to toggle against, so its expected toggle count is 0
-    regardless of ``toggle_frac``. The single source of truth for this rule
-    — the serial and batched estimators both go through it."""
-    n = sf.is_rw.shape[0]
-    idx = jnp.arange(n, dtype=jnp.int32)
-    prev_rw = _exclusive_cummax(jnp.where(sf.is_rw, idx, -1))
-    has_prev = prev_rw >= 0
+    regardless of ``toggle_frac`` (a segment's carry may supply one). The
+    single source of truth for this rule — the serial and batched
+    estimators both go through it."""
+    has_prev = sf.has_prev
     ones = jnp.where(sf.is_rw,
                      jnp.asarray(ones_frac, jnp.float32) * LINE_BITS, 0.0)
     togg = jnp.where(sf.is_rw & has_prev,
@@ -497,9 +669,11 @@ class _ScanState(NamedTuple):
 
 
 @jax.jit
-def trace_charges_scan(trace: CommandTrace, pp: PowerParams) -> jax.Array:
+def trace_charges_scan(trace: CommandTrace, pp: PowerParams,
+                       carry: SegmentCarry | None = None) -> jax.Array:
     """(N,) per-command charges (mA*cycles) from the sequential oracle —
-    the ``impl='reference'`` source for the surface decomposition."""
+    the ``impl='reference'`` source for the surface decomposition.  A
+    segment's ``carry`` is its initial state."""
     def step(s: _ScanState, x):
         cmd, bank, row, col, data, dt = x
         dtf = dt.astype(jnp.float32)
@@ -553,14 +727,15 @@ def trace_charges_scan(trace: CommandTrace, pp: PowerParams) -> jax.Array:
             charge=s.charge + charge)
         return new, charge
 
-    n = trace.n
+    if carry is None:
+        carry = empty_carry()
     init = _ScanState(
-        bank_open=jnp.zeros(N_BANKS, dtype=jnp.bool_),
-        bg_mode=jnp.asarray(BG_ACTIVE, dtype=jnp.int32),
-        prev_data=jnp.zeros(dram.LINE_WORDS, dtype=jnp.uint32),
-        has_prev=jnp.asarray(False),
-        prev_bank=jnp.asarray(-1, dtype=jnp.int32),
-        last_col_in_bank=jnp.full(N_BANKS, -1, dtype=jnp.int32),
+        bank_open=jnp.asarray(carry.open, jnp.bool_),
+        bg_mode=jnp.asarray(carry.bg_mode, jnp.int32),
+        prev_data=jnp.asarray(carry.prev_data, jnp.uint32),
+        has_prev=jnp.asarray(carry.has_prev, jnp.bool_),
+        prev_bank=jnp.asarray(carry.prev_bank, jnp.int32),
+        last_col_in_bank=jnp.asarray(carry.last_col, jnp.int32),
         charge=jnp.asarray(0.0, dtype=jnp.float32))
     xs = (trace.cmd, trace.bank, trace.row, trace.col, trace.data, trace.dt)
     _, charges = jax.lax.scan(step, init, xs)
@@ -568,6 +743,7 @@ def trace_charges_scan(trace: CommandTrace, pp: PowerParams) -> jax.Array:
 
 
 @jax.jit
-def trace_energy_scan(trace: CommandTrace, pp: PowerParams) -> EnergyReport:
-    charges = trace_charges_scan(trace, pp)
+def trace_energy_scan(trace: CommandTrace, pp: PowerParams,
+                      carry: SegmentCarry | None = None) -> EnergyReport:
+    charges = trace_charges_scan(trace, pp, carry)
     return _report(jnp.sum(charges), trace.total_cycles())

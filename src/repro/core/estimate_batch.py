@@ -49,7 +49,8 @@ import numpy as np
 
 from repro.core.dram import (CommandTrace, N_BANKS, N_ROW_BANDS, batch_traces,
                              stack_padded)
-from repro.core.energy_model import (EnergyReport, PowerParams, _report,
+from repro.core.energy_model import (EnergyReport, PowerParams,
+                                     SegmentCarry, _report,
                                      charge_from_features,
                                      distribution_features,
                                      extract_structural_features,
@@ -61,9 +62,13 @@ from repro.core.fleet import batched_pair_totals, pad_leading, pad_rows
 @dataclasses.dataclass(frozen=True)
 class TraceBatch:
     """A fixed-shape batch of command traces (leading trace axis on every
-    field) plus the validity mask that excludes padding slots."""
+    field) plus the validity mask that excludes padding slots.  ``carry``
+    (optional, a leading row axis on every leaf) makes each row a segment
+    of a longer trace: the state its commands inherit
+    (``energy_model.SegmentCarry``); None is the empty carry."""
     trace: CommandTrace   # (T, N) on every field
     weight: jax.Array     # (T, N) float32: 1 for real commands, 0 for pad
+    carry: SegmentCarry | None = None
 
     @classmethod
     def from_traces(cls, traces: Sequence[CommandTrace]) -> "TraceBatch":
@@ -124,36 +129,48 @@ def original_traces(traces, tb: TraceBatch) -> list[CommandTrace]:
             for i in range(tb.n_traces)]
 
 
+def row_carries(tb: TraceBatch) -> list:
+    """Each row's segment carry (None for every row of an uncarried
+    batch), for the pair-at-a-time ``impl='reference'`` oracles."""
+    if tb.carry is None:
+        return [None] * tb.n_traces
+    return [jax.tree_util.tree_map(lambda x: x[i], tb.carry)
+            for i in range(tb.n_traces)]
+
+
 # ---------------------------------------------------------------------------
 # The batched dispatches
 # ---------------------------------------------------------------------------
 @jax.jit
 def batched_reports(trace: CommandTrace, weight: jax.Array,
-                    stacked: PowerParams) -> EnergyReport:
+                    stacked: PowerParams,
+                    carry: SegmentCarry | None = None) -> EnergyReport:
     """Energy reports of every (trace, vendor) pair in one dispatch.
 
     ``trace``/``weight`` are a TraceBatch's padded fields; ``stacked`` is
-    ``stack_params`` over the fitted vendor params. Returns an EnergyReport
+    ``stack_params`` over the fitted vendor params; ``carry`` the rows'
+    segment carries (None: whole traces). Returns an EnergyReport
     whose every leaf has shape (traces, vendors); the charge/cycle core is
     ``fleet.batched_pair_totals``, shared with the campaign engine."""
-    def one_trace(tr: CommandTrace, w: jax.Array):
-        return batched_pair_totals(tr, w, extract_structural_features(tr),
-                                   stacked)
+    def one_trace(tr: CommandTrace, w: jax.Array, c):
+        return batched_pair_totals(
+            tr, w, extract_structural_features(tr, carry=c), stacked)
 
-    charge, cycles = jax.vmap(one_trace)(trace, weight)   # (T, V), (T,)
+    charge, cycles = jax.vmap(one_trace)(trace, weight, carry)  # (T, V), (T,)
     return _report(charge, jnp.broadcast_to(cycles[:, None], charge.shape))
 
 
 @jax.jit
 def batched_range_reports(trace: CommandTrace, weight: jax.Array,
-                          stacked: PowerParams, band: jax.Array
+                          stacked: PowerParams, band: jax.Array,
+                          carry: SegmentCarry | None = None
                           ) -> tuple[EnergyReport, EnergyReport, EnergyReport]:
     """(lo, mean, hi) report matrices across the per-vendor process-variation
     band. ``band`` is a float32 (vendors, 2) array of multiplicative
     (lo, hi) factors, broadcast over the (traces, vendors) matrix inside the
     same dispatch rather than applied to a scalar current after the fact,
     so *every* report field (charge, current, energy) carries the band."""
-    mean = batched_reports(trace, weight, stacked)
+    mean = batched_reports(trace, weight, stacked, carry)
     lo = scale_report(mean, band[None, :, 0])   # (1, V) over the trace axis
     hi = scale_report(mean, band[None, :, 1])
     return lo, mean, hi
@@ -162,7 +179,9 @@ def batched_range_reports(trace: CommandTrace, weight: jax.Array,
 @jax.jit
 def batched_distribution_reports(trace: CommandTrace, weight: jax.Array,
                                  stacked: PowerParams, ones_frac: jax.Array,
-                                 toggle_frac: jax.Array) -> EnergyReport:
+                                 toggle_frac: jax.Array,
+                                 carry: SegmentCarry | None = None
+                                 ) -> EnergyReport:
     """No-data-trace mode over the batch: expected ones/toggle fractions
     replace the per-command data features (paper Section 9.2 fallback).
 
@@ -175,17 +194,20 @@ def batched_distribution_reports(trace: CommandTrace, weight: jax.Array,
     toggle_frac = jnp.broadcast_to(jnp.asarray(toggle_frac, jnp.float32),
                                    (trace.cmd.shape[0],))
 
-    def one_trace(tr: CommandTrace, w, of, tf):
-        sf = distribution_features(extract_structural_features(tr), of, tf)
+    def one_trace(tr: CommandTrace, w, of, tf, c):
+        sf = distribution_features(extract_structural_features(tr, carry=c),
+                                   of, tf)
         return batched_pair_totals(tr, w, sf, stacked)
 
     charge, cycles = jax.vmap(one_trace)(trace, weight, ones_frac,
-                                         toggle_frac)
+                                         toggle_frac, carry)
     return _report(charge, jnp.broadcast_to(cycles[:, None], charge.shape))
 
 
 def batched_surface_reports(trace: CommandTrace, weight: jax.Array,
-                            stacked: PowerParams) -> EnergyReport:
+                            stacked: PowerParams,
+                            carry: SegmentCarry | None = None
+                            ) -> EnergyReport:
     """The fleet-wide structural-variation surfaces (``mode='surface'``):
     every (trace, vendor) pair's per-(bank, row-band) energy decomposition
     in ONE dispatch — no per-module Python sweeps.  Returns an
@@ -198,7 +220,8 @@ def batched_surface_reports(trace: CommandTrace, weight: jax.Array,
     chunked dispatch runs (:func:`_surface_chunk_charge` with the whole
     module axis as one chunk), so chunked-vs-one-shot parity is bitwise
     by construction, not merely allclose."""
-    charge = _surface_chunk_charge(trace, weight, stacked, False, False)
+    charge = _surface_chunk_charge(trace, weight, stacked, False, False,
+                                   carry)
     cycles = _surface_cycles_batch(trace, weight)          # (T, 8, R)
     return _report(charge, jnp.broadcast_to(cycles[:, None], charge.shape))
 
@@ -224,7 +247,8 @@ def batched_surface_reports(trace: CommandTrace, weight: jax.Array,
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("pallas", "interpret"))
 def _surface_chunk_charge(trace: CommandTrace, weight, chunk_pp: PowerParams,
-                          pallas: bool, interpret: bool):
+                          pallas: bool, interpret: bool,
+                          carry: SegmentCarry | None = None):
     """One module chunk's surface charge -> (T, chunk, 8, R) f32.  The
     per-pair math is verbatim :func:`batched_surface_reports` (vectorized)
     or the fused surface kernel (pallas), so chunked == one-shot holds
@@ -233,11 +257,12 @@ def _surface_chunk_charge(trace: CommandTrace, weight, chunk_pp: PowerParams,
         from repro.kernels.vampire_energy import ops as vops
         charge, _ = vops.batched_charge_matrix(trace, weight, chunk_pp,
                                                surface=True,
-                                               interpret=interpret)
+                                               interpret=interpret,
+                                               carry=carry)
         return charge
 
-    def one_trace(tr: CommandTrace, w: jax.Array):
-        sf = extract_structural_features(tr)
+    def one_trace(tr: CommandTrace, w: jax.Array, c):
+        sf = extract_structural_features(tr, carry=c)
 
         def one_paramset(pp: PowerParams):
             charges = charge_from_features(tr, finalize_features(sf, pp), pp)
@@ -245,7 +270,7 @@ def _surface_chunk_charge(trace: CommandTrace, weight, chunk_pp: PowerParams,
 
         return jax.vmap(one_paramset)(chunk_pp)            # (chunk, 8, R)
 
-    return jax.vmap(one_trace)(trace, weight)              # (T, chunk, 8, R)
+    return jax.vmap(one_trace)(trace, weight, carry)       # (T, chunk, 8, R)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -317,19 +342,23 @@ def chunked_surface_reports(trace: CommandTrace, weight, stacked: PowerParams,
 # call inside ``ops.batched_charge_matrix``.
 # ---------------------------------------------------------------------------
 def pallas_batched_reports(trace: CommandTrace, weight: jax.Array,
-                           stacked: PowerParams) -> EnergyReport:
+                           stacked: PowerParams,
+                           carry: SegmentCarry | None = None
+                           ) -> EnergyReport:
     """impl='pallas' twin of :func:`batched_reports`."""
     from repro.kernels.vampire_energy import ops as vops
-    charge, cycles = vops.batched_charge_matrix(trace, weight, stacked)
+    charge, cycles = vops.batched_charge_matrix(trace, weight, stacked,
+                                                carry=carry)
     return _report(charge, jnp.broadcast_to(cycles[:, None], charge.shape))
 
 
 def pallas_batched_range_reports(trace: CommandTrace, weight: jax.Array,
-                                 stacked: PowerParams, band: jax.Array
+                                 stacked: PowerParams, band: jax.Array,
+                                 carry: SegmentCarry | None = None
                                  ) -> tuple[EnergyReport, EnergyReport,
                                             EnergyReport]:
     """impl='pallas' twin of :func:`batched_range_reports`."""
-    mean = pallas_batched_reports(trace, weight, stacked)
+    mean = pallas_batched_reports(trace, weight, stacked, carry)
     lo = scale_report(mean, band[None, :, 0])
     hi = scale_report(mean, band[None, :, 1])
     return lo, mean, hi
@@ -339,7 +368,8 @@ def pallas_batched_distribution_reports(trace: CommandTrace,
                                         weight: jax.Array,
                                         stacked: PowerParams,
                                         ones_frac: jax.Array,
-                                        toggle_frac: jax.Array
+                                        toggle_frac: jax.Array,
+                                        carry: SegmentCarry | None = None
                                         ) -> EnergyReport:
     """impl='pallas' twin of :func:`batched_distribution_reports` (the
     feature kernel is skipped; expected fractions feed the energy kernel
@@ -347,18 +377,21 @@ def pallas_batched_distribution_reports(trace: CommandTrace,
     with first-access toggles pinned to 0)."""
     from repro.kernels.vampire_energy import ops as vops
     charge, cycles = vops.batched_charge_matrix(
-        trace, weight, stacked, ones_frac=ones_frac, toggle_frac=toggle_frac)
+        trace, weight, stacked, ones_frac=ones_frac, toggle_frac=toggle_frac,
+        carry=carry)
     return _report(charge, jnp.broadcast_to(cycles[:, None], charge.shape))
 
 
 def pallas_batched_surface_reports(trace: CommandTrace, weight: jax.Array,
-                                   stacked: PowerParams) -> EnergyReport:
+                                   stacked: PowerParams,
+                                   carry: SegmentCarry | None = None
+                                   ) -> EnergyReport:
     """impl='pallas' twin of :func:`batched_surface_reports`: the energy
     kernel swaps its scalar charge sum for an in-kernel cell reduction over
     the (bank, row-band) one-hot plane, same (vendors, traces, blocks)
     grid."""
     from repro.kernels.vampire_energy import ops as vops
     charge, cycles = vops.batched_charge_matrix(trace, weight, stacked,
-                                                surface=True)
+                                                surface=True, carry=carry)
     return _report(charge,
                    jnp.broadcast_to(cycles[:, None], charge.shape))

@@ -228,13 +228,14 @@ class Vampire(model_api.StackedEstimatorMixin):
         stacked, band = self._stacked_for(idx)
         tb = self._batch_cache.get(traces)
 
+        carry = tb.carry
         if mode == "surface":
             if impl == "vectorized":
                 return estimate_batch.batched_surface_reports(
-                    tb.trace, tb.weight, stacked)
+                    tb.trace, tb.weight, stacked, carry)
             if impl == "pallas":
                 return estimate_batch.pallas_batched_surface_reports(
-                    tb.trace, tb.weight, stacked)
+                    tb.trace, tb.weight, stacked, carry)
             return self._reference_surface(traces, tb, stacked)
 
         if mode == "distribution":
@@ -242,10 +243,11 @@ class Vampire(model_api.StackedEstimatorMixin):
                 return estimate_batch.batched_distribution_reports(
                     tb.trace, tb.weight, stacked,
                     jnp.asarray(ones_frac, jnp.float32),
-                    jnp.asarray(toggle_frac, jnp.float32))
+                    jnp.asarray(toggle_frac, jnp.float32), carry)
             if impl == "pallas":
                 return estimate_batch.pallas_batched_distribution_reports(
-                    tb.trace, tb.weight, stacked, ones_frac, toggle_frac)
+                    tb.trace, tb.weight, stacked, ones_frac, toggle_frac,
+                    carry)
             return self._reference_matrix(traces, tb, stacked,
                                           ones_frac=ones_frac,
                                           toggle_frac=toggle_frac)
@@ -253,15 +255,15 @@ class Vampire(model_api.StackedEstimatorMixin):
         if impl == "vectorized":
             if mode == "range":
                 return estimate_batch.batched_range_reports(
-                    tb.trace, tb.weight, stacked, band)
+                    tb.trace, tb.weight, stacked, band, carry)
             return estimate_batch.batched_reports(tb.trace, tb.weight,
-                                                  stacked)
+                                                  stacked, carry)
         if impl == "pallas":
             if mode == "range":
                 return estimate_batch.pallas_batched_range_reports(
-                    tb.trace, tb.weight, stacked, band)
+                    tb.trace, tb.weight, stacked, band, carry)
             return estimate_batch.pallas_batched_reports(tb.trace, tb.weight,
-                                                         stacked)
+                                                         stacked, carry)
         mean = self._reference_matrix(traces, tb, stacked)
         if mode == "mean":
             return mean
@@ -273,9 +275,11 @@ class Vampire(model_api.StackedEstimatorMixin):
                           ones_frac=None, toggle_frac=None) -> EnergyReport:
         """``impl='reference'``: the pair-at-a-time oracle — the lax.scan
         per-command state machine for measured-data modes, the per-trace
-        feature-override path for ``mode='distribution'``."""
-        from repro.core.estimate_batch import original_traces
+        feature-override path for ``mode='distribution'``.  A carried
+        batch's rows start from their carries."""
+        from repro.core.estimate_batch import original_traces, row_carries
         originals = original_traces(traces, tb)
+        carries = row_carries(tb)
         if ones_frac is not None:
             of = np.broadcast_to(np.asarray(ones_frac, np.float32),
                                  (len(originals),))
@@ -284,7 +288,8 @@ class Vampire(model_api.StackedEstimatorMixin):
 
             def one_pair(tr, pp, i):
                 sf = distribution_features(
-                    extract_structural_features(tr), of[i], tf[i])
+                    extract_structural_features(tr, carry=carries[i]),
+                    of[i], tf[i])
                 charges = charge_from_features(
                     tr, finalize_features(sf, pp), pp)
                 return _report(jnp.sum(charges), tr.total_cycles())
@@ -293,8 +298,9 @@ class Vampire(model_api.StackedEstimatorMixin):
                                   )(stacked)
                          for i, tr in enumerate(originals)]
         else:
-            per_trace = [jax.vmap(lambda pp, tr=tr: trace_energy_scan(tr, pp)
-                                  )(stacked) for tr in originals]
+            per_trace = [jax.vmap(lambda pp, tr=tr, c=c:
+                                  trace_energy_scan(tr, pp, c))(stacked)
+                         for tr, c in zip(originals, carries)]
         return jax.tree_util.tree_map(lambda *rows: jnp.stack(rows),
                                       *per_trace)
 
@@ -303,17 +309,17 @@ class Vampire(model_api.StackedEstimatorMixin):
         """``impl='reference'`` for ``mode='surface'``: the per-command
         lax.scan oracle's charge stream, grouped onto the (bank, row-band)
         cells one (trace, vendor) pair at a time."""
-        from repro.core.estimate_batch import original_traces
+        from repro.core.estimate_batch import original_traces, row_carries
         originals = original_traces(traces, tb)
 
-        def one_pair(tr, pp):
-            charges = trace_charges_scan(tr, pp)
+        def one_pair(tr, pp, c):
+            charges = trace_charges_scan(tr, pp, c)
             w = jnp.ones_like(charges)
             return _report(surface_charge(tr, w, charges),
                            surface_cycles(tr, w))
 
-        per_trace = [jax.vmap(lambda pp, tr=tr: one_pair(tr, pp))(stacked)
-                     for tr in originals]
+        per_trace = [jax.vmap(lambda pp, tr=tr, c=c: one_pair(tr, pp, c))(
+            stacked) for tr, c in zip(originals, row_carries(tb))]
         return jax.tree_util.tree_map(lambda *rows: jnp.stack(rows),
                                       *per_trace)
 

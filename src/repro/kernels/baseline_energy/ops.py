@@ -14,18 +14,18 @@ from repro.core.energy_model import (structural_state, surface_cells,
                                      surface_cycles)
 from repro.kernels.baseline_energy.baseline_energy import \
     baseline_energy_pallas
-from repro.kernels.common import interpret_default, pad_batch
+from repro.kernels.common import interpret_default, pad_batch, pad_carry
 
 
 @functools.partial(jax.jit,
                    static_argnames=("kind", "surface", "block_n",
                                     "interpret", "grid_layout"))
 def _baseline_charge_matrix(trace: CommandTrace, weight,
-                            tiled: CommandTrace, w_tiled, table, kind: str,
-                            surface: bool, block_n: int, interpret: bool,
-                            grid_layout: str):
+                            tiled: CommandTrace, w_tiled, table, carry,
+                            kind: str, surface: bool, block_n: int,
+                            interpret: bool, grid_layout: str):
     t = trace.cmd.shape[0]
-    st = jax.vmap(structural_state)(tiled)
+    st = jax.vmap(structural_state)(tiled, carry)
     planes = {
         "dt": tiled.dt.astype(jnp.float32),
         "is_rd": (tiled.cmd == RD).astype(jnp.float32),
@@ -36,7 +36,10 @@ def _baseline_charge_matrix(trace: CommandTrace, weight,
         "pd": st.bg_state.astype(jnp.float32),
         "w": w_tiled.astype(jnp.float32),
     }
-    any_act = jnp.any(tiled.cmd == ACT, axis=1).astype(jnp.float32)
+    any_act = jnp.any(tiled.cmd == ACT, axis=1)
+    if carry is not None:
+        any_act = any_act | carry.any_act     # the whole trace's ACTs
+    any_act = any_act.astype(jnp.float32)
     if surface:
         charge = baseline_energy_pallas(kind, planes, any_act, table,
                                         block_n=block_n, interpret=interpret,
@@ -55,11 +58,12 @@ def _baseline_charge_matrix(trace: CommandTrace, weight,
 def baseline_charge_matrix(trace: CommandTrace, weight, table, kind: str, *,
                            surface: bool = False, block_n: int | None = None,
                            interpret: bool | None = None,
-                           grid_layout: str | None = None):
+                           grid_layout: str | None = None, carry=None):
     """Masked charge of every (trace, vendor) pair for one baseline kind
     -> ``((T, V) charge in mA*cycles, (T,) masked cycles)``, or with
     ``surface=True`` the per-(bank, row-band) structural decomposition
-    ``((T, V, 8, N_ROW_BANDS) charge, (T, 8, N_ROW_BANDS) cycles)``.
+    ``((T, V, 8, N_ROW_BANDS) charge, (T, 8, N_ROW_BANDS) cycles)``;
+    ``carry`` the rows' segment carries (None for whole traces).
     ``block_n``/``grid_layout`` default to the autotuner's committed
     winner for this (backend, shape-bucket)
     (``kernels.autotune.best_config``)."""
@@ -73,6 +77,7 @@ def baseline_charge_matrix(trace: CommandTrace, weight, table, kind: str, *,
         grid_layout = (cfg["layout"] if grid_layout is None
                        else grid_layout)
     tiled, w_tiled = pad_batch(trace, weight, block_n)
+    carry = pad_carry(carry, w_tiled.shape[0])
     return _baseline_charge_matrix(trace, weight, tiled, w_tiled, table,
-                                   kind, surface, block_n, interpret,
+                                   carry, kind, surface, block_n, interpret,
                                    grid_layout)

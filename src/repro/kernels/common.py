@@ -81,6 +81,17 @@ def pad_batch(trace, weight, block_n: int):
     return jax.tree_util.tree_map(pad, trace), pad(weight)
 
 
+def pad_carry(carry, rows: int):
+    """Pad a batch's segment carries (leading row axis) with zero rows up
+    to ``rows``, the trace axis :func:`pad_batch` made; the pad rows have
+    zero weight, so what they carry changes no result."""
+    if carry is None:
+        return None
+    return jax.tree_util.tree_map(
+        lambda x: jnp.pad(jnp.asarray(x), [(0, rows - x.shape[0])]
+                          + [(0, 0)] * (x.ndim - 1)), carry)
+
+
 def grid_maps(grid_layout: str, n_vendors: int, n_traces: int, grid_n: int):
     """The grid tuple plus an index-map builder for one grid-major order.
 

@@ -29,7 +29,7 @@ from repro.core.dram import (ACT, LINE_BITS, N_BANKS, N_ROW_BANDS, REF,
 from repro.core.energy_model import (EnergyReport, N_SURFACE_CELLS,
                                      PowerParams, _report, structural_state,
                                      surface_cells, surface_cycles)
-from repro.kernels.common import interpret_default, pad_batch
+from repro.kernels.common import interpret_default, pad_batch, pad_carry
 from repro.kernels.vampire_energy.vampire_energy import (
     batched_energy_pallas, batched_features_pallas, pack_params)
 
@@ -40,10 +40,10 @@ from repro.kernels.vampire_energy.vampire_energy import (
 def _vampire_charge_matrix(trace: CommandTrace, weight,
                            tiled: CommandTrace, w_tiled,
                            stacked: PowerParams, ones_frac, toggle_frac,
-                           surface: bool, block_n: int, interpret: bool,
-                           grid_layout: str):
+                           carry, surface: bool, block_n: int,
+                           interpret: bool, grid_layout: str):
     t = trace.cmd.shape[0]
-    st = jax.vmap(structural_state)(tiled)
+    st = jax.vmap(structural_state)(tiled, carry)
     if ones_frac is None:
         # measured-data modes: the fused popcount/toggle feature kernel
         # over the whole batch's data stream, once
@@ -100,14 +100,16 @@ def batched_charge_matrix(trace: CommandTrace, weight, stacked: PowerParams,
                           *, ones_frac=None, toggle_frac=None,
                           surface: bool = False, block_n: int | None = None,
                           interpret: bool | None = None,
-                          grid_layout: str | None = None):
+                          grid_layout: str | None = None, carry=None):
     """Masked charge of every (trace, paramset) pair through the fused
     kernels -> ``((T, V) charge in mA*cycles, (T,) masked cycles)``, or
     with ``surface=True`` the structural decomposition
     ``((T, V, 8, N_ROW_BANDS) charge, (T, 8, N_ROW_BANDS) cycles)``.
 
     ``trace``/``weight`` are a padded TraceBatch's (T, N) fields;
-    ``stacked`` carries a leading paramset axis.  ``interpret`` resolves
+    ``stacked`` carries a leading paramset axis; ``carry`` the rows'
+    segment carries (``energy_model.SegmentCarry``, None for whole
+    traces), which seed ``structural_state``.  ``interpret`` resolves
     per call (compiled on TPU, interpreted elsewhere) BEFORE entering the
     jitted body, so it participates in the jit cache key.  ``block_n`` /
     ``grid_layout`` likewise resolve per call: when not pinned by the
@@ -124,9 +126,10 @@ def batched_charge_matrix(trace: CommandTrace, weight, stacked: PowerParams,
         grid_layout = (cfg["layout"] if grid_layout is None
                        else grid_layout)
     tiled, w_tiled = pad_batch(trace, weight, block_n)
+    carry = pad_carry(carry, w_tiled.shape[0])
     return _vampire_charge_matrix(trace, weight, tiled, w_tiled, stacked,
-                                  ones_frac, toggle_frac, surface, block_n,
-                                  interpret, grid_layout)
+                                  ones_frac, toggle_frac, carry, surface,
+                                  block_n, interpret, grid_layout)
 
 
 def trace_energy_kernel(trace: CommandTrace, pp: PowerParams) -> EnergyReport:

@@ -8,7 +8,8 @@ never re-transfer per tick (the PR 3 pytree property is exactly the hook:
 ``device_put`` preserves the identity-hashed aux, so the resident model's
 treedef equals the original's and jit caches keyed on it keep hitting).
 
-Dispatch is the ring's bucket-shaped :class:`TraceBatch` through
+Dispatch is the ring's bucket-shaped :class:`TraceBatch` (with its rows'
+segment carries) through
 ``model.estimate(...)``, wrapped in ``jax.jit`` and — on a multi-device
 mesh — ``shard_map`` with the trace axis split over EVERY mesh axis
 (``P(("data", "model"))``): per-trace estimation is embarrassingly
@@ -37,6 +38,7 @@ import jax
 from repro.core import model_api
 from repro.core.estimate_batch import TraceBatch
 from repro.core.fleet import pad_rows
+from repro.kernels.common import pad_carry
 from repro.runtime.spans import span
 
 
@@ -79,10 +81,11 @@ class ServingEngine:
         with span("engine.dispatch"):
             if self.n_shards == 1:
                 return self._dispatch_fn(vendors, False)(
-                    self.resident, tb.trace, tb.weight)
+                    self.resident, tb.trace, tb.weight, tb.carry)
             trace, weight = pad_rows(tb.trace, tb.weight, self.n_shards)
+            carry = pad_carry(tb.carry, weight.shape[0])
             out = self._dispatch_fn(vendors, True)(self.resident, trace,
-                                                   weight)
+                                                   weight, carry)
             return jax.tree_util.tree_map(lambda x: x[:tb.n_traces], out)
 
     def _dispatch_fn(self, vendors, sharded: bool):
@@ -92,9 +95,9 @@ class ServingEngine:
         # compiled program instead of recompiling the world.
         fn = self._fns.get((vendors, sharded))
         if fn is None:
-            def serve_estimate(m, trace, weight):
+            def serve_estimate(m, trace, weight, carry):
                 return m.estimate(
-                    TraceBatch(trace, weight), vendors, mode=self.mode,
+                    TraceBatch(trace, weight, carry), vendors, mode=self.mode,
                     impl=self.impl, ones_frac=self.ones_frac,
                     toggle_frac=self.toggle_frac)
 
@@ -103,7 +106,7 @@ class ServingEngine:
                 spec = P(tuple(self.mesh.axis_names))
                 serve_estimate = jax.shard_map(
                     serve_estimate, mesh=self.mesh,
-                    in_specs=(P(), spec, spec), out_specs=spec,
+                    in_specs=(P(), spec, spec, spec), out_specs=spec,
                     check_vma=False)
             fn = jax.jit(serve_estimate)
             self._fns[(vendors, sharded)] = fn

@@ -11,14 +11,18 @@ Admission routes every ingested trace through the ``trace_lint`` JEDEC
 gate: a protocol-illegal trace is returned as a structured
 :class:`Rejection` (rule id, command index, bank — the linter's
 diagnostics verbatim), never silently priced, and never blocks the legal
-traces admitted alongside it.  A trace longer than the ring's largest
-length bucket rejects the same way (reason ``'too-long'``).
+traces admitted alongside it.  A trace longer than a whole ring window
+(largest count bucket x largest length bucket) rejects the same way
+(reason ``'too-long'``).  A trace past the largest length bucket is cut
+into rows at admission (``ring.split``): the lint and the ring read the
+same cut, each row with the carry of the commands before it, and the
+ticket's rows are summed into one report before it is returned.
 
 Dispatch happens on :meth:`step` (one ring window), :meth:`maybe_step`
 (cadence-gated, for an ingestion loop's hot path), or :meth:`drain`
 (flush everything — shutdown).  Results are keyed by ticket: each
-admitted trace's row of the batched report matrix, sliced out after the
-dispatch completes.
+admitted trace's rows of the batched report matrix, combined and sliced
+out after the dispatch completes.
 
 :meth:`metrics` snapshots the per-dispatch counters the ROADMAP's
 serving item asks for: queue depth, batch fill, sustained traces/s,
@@ -36,10 +40,12 @@ from typing import Sequence
 import jax
 import numpy as np
 
-from repro.core.dram import CommandTrace
+from repro.core.dram import TCK_NS, VDD, CommandTrace
+from repro.core.energy_model import EnergyReport
 from repro.runtime.spans import span
 from repro.serving.engine import ServingEngine
-from repro.serving.ring import RingConfig, TraceRing, TraceTooLongError
+from repro.serving.ring import (RingConfig, TraceRing, TraceTooLongError,
+                                split)
 
 #: completions (dispatches) the latency (dispatch-time and fill) samples
 #: keep, and rejections :attr:`EstimationService.rejections` keeps
@@ -105,6 +111,49 @@ class MetricsSnapshot:
     recalibrations: int = 0      # refits pushed through update_model
 
 
+def combine_rows(rep, rows: Sequence[int]):
+    """A window's host report (leaves with a leading row axis; a
+    ``(lo, mean, hi)`` triple in range mode) -> one entry per ticket,
+    ticket ``i`` owning ``rows[i]`` consecutive rows.  The charge and the
+    cycles are summed over a ticket's rows (per cell in surface mode; the
+    cycles in int64, since a whole trace may pass 2^31); the current,
+    energy and time are derived from those sums, never summed.  A one-row
+    ticket keeps its row as it is."""
+    if isinstance(rep, tuple) and not isinstance(rep, EnergyReport):
+        return tuple(combine_rows(r, rows) for r in rep)
+    rows = np.asarray(rows)
+    if (rows == 1).all():
+        return rep
+    starts = np.concatenate([[0], np.cumsum(rows)[:-1]])
+    charge = np.add.reduceat(rep.charge_ma_cycles.astype(np.float64),
+                             starts, axis=0)
+    cycles = np.add.reduceat(rep.cycles.astype(np.int64), starts, axis=0)
+    whole = {"charge_ma_cycles": charge, "cycles": cycles,
+             "avg_current_ma": charge / np.maximum(cycles, 1),
+             "energy_pj": charge * TCK_NS * VDD,
+             "time_ns": cycles * TCK_NS}
+    one = (rows == 1).reshape((-1,) + (1,) * (charge.ndim - 1))
+    return EnergyReport(**{
+        f: np.where(one, getattr(rep, f)[starts], whole[f]).astype(
+            np.int64 if f == "cycles" else getattr(rep, f).dtype)
+        for f in EnergyReport._fields})
+
+
+def _windows(items: Sequence[int], rows: Sequence[int], limit: int
+             ) -> list[list[int]]:
+    """``items`` in order, cut into runs whose ``rows`` sum to at most
+    ``limit`` (each item's rows are at most ``limit``)."""
+    out: list[list[int]] = []
+    room = 0
+    for item, n in zip(items, rows):
+        if n > room:
+            out.append([])
+            room = limit
+        out[-1].append(item)
+        room -= n
+    return out
+
+
 def _pct(samples: Sequence[float], q: float) -> float:
     return float(np.percentile(np.asarray(samples), q) * 1e3) \
         if samples else 0.0
@@ -166,20 +215,39 @@ class EstimationService:
     def submit_many(self, traces: Sequence[CommandTrace],
                     vendors: Sequence[int] | None = None
                     ) -> tuple[list[int | None], list[Rejection]]:
-        """Admit a burst: ONE batched lint over the whole burst,
-        then per-trace admission.  Illegal traces become
+        """Admit a burst: each trace cut into ring rows with their
+        carries, ONE batched lint over the whole burst's rows, then
+        per-trace admission.  Illegal traces become
         :class:`Rejection`\\ s (their slot in ``tickets`` is ``None``);
         the legal ones are admitted regardless — a mixed burst never
         blocks its clean members.  ``vendors`` scopes the whole burst
-        (the ring groups windows by vendor subset)."""
+        (the ring groups windows by vendor subset).
+
+        The lint runs at most one window of rows at a time (its rows
+        padded to a power of two), so the shapes it compiles are bounded
+        as the ring's are.  Span ``trace.segment``: the cut and the carry scan
+        (counts ``segments``, the rows made, and ``long_traces``, the
+        traces that needed more than one)."""
         if self._closed:
             raise RuntimeError("service is closed")
         from repro.analysis import trace_lint
+        ring = self.ring.config
         traces = list(traces)
+        with span("trace.segment") as s:
+            segs = [split(tr, ring.max_length)
+                    if int(tr.n) <= ring.max_commands else None
+                    for tr in traces]
+            kept = [i for i, g in enumerate(segs) if g is not None]
+            s.attrs["segments"] = sum(segs[i].rows for i in kept)
+            s.attrs["long_traces"] = sum(segs[i].rows > 1 for i in kept)
         errors_by_trace: dict[int, list] = {}
-        if self.config.lint and traces:
-            for d in trace_lint.errors_of(trace_lint.lint_traces(traces)):
-                errors_by_trace.setdefault(d.trace_index, []).append(d)
+        if self.config.lint:
+            for chunk in _windows(kept, [segs[i].rows for i in kept],
+                                  ring.max_batch):
+                for d in trace_lint.errors_of(trace_lint.lint_traces(
+                        [segs[i] for i in chunk])):
+                    errors_by_trace.setdefault(chunk[d.trace_index],
+                                               []).append(d)
         group = (tuple(int(v) for v in vendors)
                  if vendors is not None else None)
         tickets: list[int | None] = []
@@ -195,7 +263,8 @@ class EstimationService:
                 tickets.append(None)
                 continue
             try:
-                self.ring.admit(tr, ticket=ticket, group=group)
+                self.ring.admit(tr if segs[i] is None else segs[i],
+                                ticket=ticket, group=group)
             except TraceTooLongError:
                 rejections.append(self._reject(
                     Rejection(ticket, "too-long", ())))
@@ -223,7 +292,8 @@ class EstimationService:
 
         Spans (besides the ring's and the engine's): ``engine.block``,
         the wait for the device, and ``service.slice``, the report to the
-        host and its per-ticket rows (``bytes`` fetched)."""
+        host and its per-ticket rows (``bytes`` fetched), around
+        ``service.combine``, the rows summed per ticket (``rows``)."""
         rb = self.ring.take(self.config.max_batch)
         if rb is None:
             return 0
@@ -240,9 +310,13 @@ class EstimationService:
         with span("service.slice") as s:
             s.attrs["bytes"] = sum(x.nbytes
                                    for x in jax.tree_util.tree_leaves(rep))
+            rep = jax.tree_util.tree_map(np.asarray, rep)
+            with span("service.combine") as c:
+                rep = combine_rows(rep, rb.rows)
+                c.attrs["rows"] = sum(rb.rows)
             for i, ticket in enumerate(rb.tickets):
                 self._results[ticket] = jax.tree_util.tree_map(
-                    lambda x: np.asarray(x)[i], rep)
+                    lambda x: x[i], rep)
                 done = time.perf_counter()
                 self._latency_s.append(done - self._submit_t.pop(ticket, t0))
                 self._completed += 1

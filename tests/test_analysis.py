@@ -15,6 +15,7 @@ from repro.core import dram, idd_loops, traces
 from repro.core.dram import (ACT, NOP, PDE, PDE_SLOW, PDX, PRE, PREA, RD,
                              REF, SRE, SRX, TIMING, WR)
 from repro.runtime.spans import RECORDER
+from repro.serving.ring import segment
 
 T = TIMING
 
@@ -22,14 +23,14 @@ T = TIMING
 def raw_trace(script):
     """Build a CommandTrace from (cmd, bank, dt) triples WITHOUT the
     construction-time low-power validation (the linter is the system under
-    test; it must see illegal streams)."""
-    import jax.numpy as jnp
+    test; it must see illegal streams).  Host arrays: a device array of a
+    new length would compile a program of its own inside a timed
+    Hypothesis example."""
     cmd, bank, dt = (np.array(c, np.int32) for c in zip(*script))
     n = len(cmd)
-    z = jnp.zeros(n, jnp.int32)
-    return dram.CommandTrace(jnp.asarray(cmd), jnp.asarray(bank), z, z,
-                             jnp.zeros((n, dram.LINE_WORDS), jnp.uint32),
-                             jnp.asarray(dt))
+    z = np.zeros(n, np.int32)
+    return dram.CommandTrace(cmd, bank, z, z,
+                             np.zeros((n, dram.LINE_WORDS), np.uint32), dt)
 
 
 def fired(trace):
@@ -143,29 +144,40 @@ _STEP = st.tuples(_CMDS, st.integers(min_value=0, max_value=7),
 
 @pytest.fixture(scope="module")
 def compiled_lint_programs():
-    """Both batched lint programs compiled, for one trace, at every padded
-    length a 1-40 step script reaches (2 through 64), so that no
-    Hypothesis example is timed through a compile."""
+    """Both batched lint programs compiled at every shape an example can
+    reach, so that no Hypothesis example is timed through a compile: one
+    row or a stream cut into two, at every padded row length a 1-40 step
+    script reaches (2 through 64), with no row, one row or both rows
+    firing (``lint_rules`` then runs over 1 or 2 rows)."""
     for length in (2, 4, 8, 16, 32, 64):
-        for first in (NOP, RD):                 # clean; BANK_RW_CLOSED
-            script = [(first, 0, 1)] + [(NOP, 0, 1)] * (length - 1)
+        clean = [(NOP, 0, 1)] * length
+        fires = [(RD, 0, 1)] + clean[1:]        # BANK_RW_CLOSED
+        for script in (clean, fires):
             trace_lint.lint_traces([raw_trace(script)])
+        for a, b in ((clean, clean), (clean, fires), (fires, fires)):
+            trace_lint.lint_traces([segment(raw_trace(a + b),
+                                            [0, length, 2 * length])])
 
 
 @settings(max_examples=30)
-@given(script=st.lists(_STEP, min_size=1, max_size=40))
+@given(script=st.lists(_STEP, min_size=1, max_size=40),
+       cut=st.integers(min_value=1, max_value=39))
 def test_property_engines_agree_on_arbitrary_streams(compiled_lint_programs,
-                                                      script):
-    """The vectorized numpy engine, the jitted batched engine, and the
-    independent reference walk produce identical diagnostics for ANY
-    command stream, legal or not."""
+                                                      script, cut):
+    """The vectorized numpy engine, the jitted batched engine (whole, and
+    with the stream cut into two rows at ``cut``, the second carrying
+    the first's state), and the independent reference walk produce
+    identical diagnostics, in whole-stream indices, for ANY command
+    stream, legal or not."""
     tr = raw_trace(script)
     key = lambda ds: sorted((d.rule, d.cmd_index, d.bank, d.margin)
                             for d in ds)
     vec = key(trace_lint.lint_trace(tr))
     ref = key(trace_lint.reference_lint(tr))
     bat = key(trace_lint.lint_traces([tr]))
-    assert vec == ref == bat
+    seg = key(trace_lint.lint_traces([
+        segment(tr, [0, cut, len(script)]) if cut < len(script) else tr]))
+    assert vec == ref == bat == seg
 
 
 def full_program_diagnostics(traces):
@@ -173,8 +185,9 @@ def full_program_diagnostics(traces):
     as it was before it counted first."""
     batch, _ = dram.batch_traces([(tr, 0) for tr in traces])
     cmd = np.asarray(batch.cmd)
+    carry = np.tile(trace_lint.empty_carry(), (len(traces), 1))
     stacks = [np.asarray(x) for x in trace_lint._programs()[1](
-        batch.cmd, batch.bank, batch.dt)]
+        batch.cmd, batch.bank, batch.dt, carry)]
     return [d for ti in range(len(traces))
             for d in trace_lint._extract(*(x[ti] for x in stacks), cmd[ti],
                                          ti)]

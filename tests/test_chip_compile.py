@@ -113,3 +113,16 @@ def test_full_width_decode_step_fits_one_chip(one_chip):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 1e9 < used < HBM_BYTES, used
+
+
+def test_lint_count_with_segment_carry_fits_one_chip(one_chip):
+    """The lint gate's counting program at the window of a whole 1 M-command
+    trace (64 rows of 16384) with its (rows, CARRY_WIDTH) carry plane."""
+    from repro.analysis import trace_lint
+    lint_count, _ = trace_lint._programs()
+    plane = _shape(one_chip, (T, N), jnp.int32)
+    compiled = lint_count.lower(
+        plane, plane, plane,
+        _shape(one_chip, (T, trace_lint.CARRY_WIDTH), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES

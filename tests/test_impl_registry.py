@@ -101,6 +101,38 @@ def test_golden_parity_every_estimator_and_impl(estimators, ragged, mode):
                                 f"leaf {name}")
 
 
+@pytest.mark.parametrize("mode", ("mean", "range", "distribution", "surface"))
+def test_golden_parity_on_carried_batches(estimators, ragged, mode):
+    """Rows that are segments of longer traces, each with the carry of the
+    commands before it: every impl honours the carry, row by row ('pallas'
+    and 'reference' against 'vectorized', the golden tolerance)."""
+    from repro.core.estimate_batch import TraceBatch
+    from repro.serving.ring import segment
+    rows, carries = [], []
+    for tr in ragged:
+        n = int(tr.n)
+        bounds = [0, n // 3, 2 * n // 3, n]
+        host = dram.CommandTrace(*(np.asarray(x) for x in tr))
+        rows += [dram.CommandTrace(*(x[lo:hi] for x in host))
+                 for lo, hi in zip(bounds[:-1], bounds[1:])]
+        carries.append(segment(host, bounds).carry)
+    batch = TraceBatch.from_traces(rows)
+    carry = type(carries[0])(*(np.concatenate(f) for f in zip(*carries)))
+    tb = TraceBatch(batch.trace, batch.weight, carry)
+    assert carry.has_prev.any() and carry.open.any()
+    kw = MODE_KW[mode]
+    for est in estimators:
+        base = est.estimate(tb, mode=mode, **kw)
+        for impl in ("pallas", "reference"):
+            other = est.estimate(tb, mode=mode, impl=impl, **kw)
+            for b, o in zip(_reports(base, mode), _reports(other, mode)):
+                for name, lb, lo in zip(b._fields, b, o):
+                    np.testing.assert_allclose(
+                        np.asarray(lo), np.asarray(lb), rtol=1e-5,
+                        err_msg=f"{est.kind} mode={mode} impl={impl} "
+                                f"leaf {name}")
+
+
 def test_vendor_subset_parity(estimators, ragged):
     for est in estimators:
         full = est.estimate(ragged, impl="pallas")

@@ -54,12 +54,35 @@ def test_ring_pads_to_bucket_shapes():
     assert len(ring) == 0 and ring.take() is None
 
 
-def test_ring_rejects_trace_longer_than_largest_bucket():
-    ring = TraceRing(RingConfig(length_buckets=(64, 128),
-                                count_buckets=(4,)))
-    with pytest.raises(TraceTooLongError) as ei:
-        ring.admit(idd_loops.validation_sweep(16))   # 144 commands
-    assert ei.value.n == 144 and ei.value.limit == 128
+@pytest.mark.parametrize("case", ["past-window", "admitted-as-rows"])
+def test_ring_rejects_trace_longer_than_largest_bucket(case, quick_vampire):
+    """The limit is one whole window, count x length buckets (4 x 128 =
+    512 commands here): past it a trace is refused; under it a trace past
+    the largest length bucket is admitted as rows of one ticket, and its
+    one report is the whole trace's."""
+    config = RingConfig(length_buckets=(64, 128), count_buckets=(4,))
+    if case == "past-window":
+        with pytest.raises(TraceTooLongError) as ei:
+            TraceRing(config).admit(idd_loops.validation_sweep(64))  # 528
+        assert ei.value.n == 528 and ei.value.limit == 512
+        return
+    tr = idd_loops.validation_sweep(16)                  # 144 commands
+    ring = TraceRing(config)
+    ring.admit(tr)
+    rb = ring.take()
+    assert rb.tickets == (0,) and rb.rows == (2,) and rb.slots == 4
+    np.testing.assert_array_equal(np.asarray(rb.batch.weight).sum(axis=1),
+                                  [128, 16, 0, 0])
+    svc = EstimationService(quick_vampire, ServiceConfig(ring=config))
+    ticket = svc.submit(tr)
+    assert svc.step() == 1 and len(svc.ring) == 0
+    got = svc.result(ticket)
+    whole = quick_vampire.estimate([tr], impl="reference")
+    for f in whole._fields:
+        # float32 sums of two rows against one: a few ulps of the total
+        np.testing.assert_allclose(np.asarray(getattr(got, f)),
+                                   np.asarray(getattr(whole, f))[0],
+                                   rtol=1e-6)
 
 
 def test_ring_windows_group_by_vendor_subset_fifo():
@@ -185,7 +208,7 @@ def test_service_mixed_admission_rejects_and_still_dispatches(quick_vampire):
 def test_service_too_long_is_a_structured_rejection(quick_vampire):
     svc = EstimationService(quick_vampire, ServiceConfig(
         ring=RingConfig(length_buckets=(64,), count_buckets=(4,))))
-    r = svc.submit(idd_loops.validation_sweep(16))     # 144 > 64
+    r = svc.submit(idd_loops.validation_sweep(32))     # 272 > 4 x 64
     assert r.reason == "too-long" and r.rules == ("too-long",)
     assert svc.metrics().rejected_by_rule == {"too-long": 1}
 

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.analysis import trace_lint
 from repro.core import fleet, idd_loops
 from repro.core.dram import CommandTrace, batch_traces
 from repro.runtime import spans
@@ -18,9 +19,10 @@ from repro.runtime.spans import COMPILE, RECORDER, Record, Recorder, span
 from repro.serving import EstimationService, ServiceConfig
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
-SERVE_SPANS = ["lint.pack", "lint.rules", "lint.fetch", "lint.extract",
-               "ring.repad", "ring.transfer", "engine.dispatch",
-               "engine.block", "service.slice"]
+SERVE_SPANS = ["trace.segment", "lint.pack", "lint.rules", "lint.fetch",
+               "lint.extract", "ring.repad", "ring.transfer",
+               "engine.dispatch", "engine.block", "service.combine",
+               "service.slice"]
 
 
 def _since(t0: float) -> list[Record]:
@@ -119,17 +121,23 @@ def test_service_records_serve_spans_in_order(quick_vampire):
     by = {r.name: r for r in recs}
     repad = by["ring.repad"].attrs
     lengths = [int(tr.n) for tr in trs]
-    assert repad["n_real"] == 3
+    # three traces within the largest length bucket: one row each
+    assert by["trace.segment"].attrs == {"segments": 3, "long_traces": 0}
+    assert by["service.combine"].attrs == {"rows": 3}
+    assert repad["n_real"] == 3 and repad["rows"] == 3
     assert repad["real_cmds"] == sum(lengths)
     assert repad["slot_cmds"] == 8 * 256              # count x length bucket
     assert repad["wait_s"] >= 0
-    # cmd, bank, row, col, dt (int32), the 16-word line, the f32 weight
+    # cmd, bank, row, col, dt (int32), the 16-word line, the f32 weight;
+    # one-row traces carry nothing: their empty carries stay on the device
     assert by["ring.transfer"].attrs["bytes"] == 8 * 256 * (5 * 4 + 64 + 4)
-    # the lint's cmd, bank and dt planes (int32), padded to a power of 2
-    assert by["lint.rules"].attrs["bytes"] == 3 * 3 * 4 * (
+    # the lint's cmd, bank and dt planes (int32), the rows and their
+    # length padded to a power of 2 (4 rows); whole traces' empty carries
+    # stay on the device
+    assert by["lint.rules"].attrs["bytes"] == 4 * 3 * 4 * (
         1 << max(max(lengths) - 1, 1).bit_length())
-    # nothing fired: only the three traces' int32 counts came down
-    assert by["lint.fetch"].attrs == {"bytes": 3 * 4, "fired_traces": 0}
+    # nothing fired: only the four rows' int32 counts came down
+    assert by["lint.fetch"].attrs == {"bytes": 4 * 4, "fired_traces": 0}
     # the report: one float per (bucket slot, vendor) for each leaf
     assert by["service.slice"].attrs["bytes"] > 0
     assert by["service.slice"].attrs["bytes"] % (8 * 4) == 0
@@ -158,7 +166,8 @@ def test_service_keeps_only_recent_rejections_but_counts_all(
     svc = EstimationService(quick_vampire, ServiceConfig(
         ring=RingConfig(length_buckets=(8,), count_buckets=(8,)),
         lint=False))
-    _, rejections = svc.submit_many([idd_loops.validation_sweep(1)] * 3)
+    # 80 commands: past a whole window of 8 rows of 8
+    _, rejections = svc.submit_many([idd_loops.validation_sweep(8)] * 3)
     assert [r.reason for r in rejections] == ["too-long"] * 3
     assert svc.rejections == tuple(rejections[1:])
     assert svc.metrics().rejected == 3
@@ -202,11 +211,12 @@ def test_lint_records_every_span_whether_or_not_a_trace_fired():
                                           "lint.fetch", "lint.extract"]
         fetch = recs[2].attrs
         assert fetch["fired_traces"] == fired == len(diags)
-        # the two int32 counts, then one fired row's planes up and its
-        # (R, N) bool mask, int32 deficits and int32 banks down
+        # the two int32 counts, then one fired row's planes and carry up
+        # and its (R, N) bool mask, int32 deficits and int32 banks down
+        carry = 4 * trace_lint.CARRY_WIDTH
         n = recs[1].attrs["bytes"] // (3 * 4 * 2)
         assert fetch["bytes"] == 2 * 4 + fired * (
-            3 * 4 * n + len(trace_lint.RULES) * n * 9)
+            3 * 4 * n + carry + len(trace_lint.RULES) * n * 9)
 
 
 # ---------------------------------------------------------------------------
@@ -263,9 +273,10 @@ def test_baseline_kernels_carry_their_names(kind, surface):
 def test_lint_and_serve_programs_carry_their_names(quick_vampire):
     from repro.analysis import trace_lint
     z = jnp.zeros((8, 256), jnp.int32)
+    c = jnp.zeros((8, trace_lint.CARRY_WIDTH), jnp.int32)
     lint_count, lint_rules = trace_lint._programs()
-    assert "jit_lint_count" in lint_count.lower(z, z, z).as_text()
-    assert "jit_lint_rules" in lint_rules.lower(z, z, z).as_text()
+    assert "jit_lint_count" in lint_count.lower(z, z, z, c).as_text()
+    assert "jit_lint_rules" in lint_rules.lower(z, z, z, c).as_text()
     svc = EstimationService(quick_vampire, ServiceConfig())
     svc.submit_many([idd_loops.validation_sweep(1)])
     svc.drain()
