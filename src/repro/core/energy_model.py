@@ -358,6 +358,105 @@ def _carried(flag) -> jax.Array:
     return jnp.where(flag, -1, _NONE)
 
 
+#: a packed key is ``position << 16 | half``: a 16-bit half of a value
+#: below the position (0 the carry, i + 1 command i) of its event
+_HALF = 16
+
+
+def gather_free(n: int) -> bool:
+    """Whether :func:`structural_state` reads earlier commands' fields at
+    row length ``n`` with packed-key cummaxes and one-hot selects, no
+    gather: every position 0..n fits above a half in a non-negative
+    int32.  Longer rows gather."""
+    return n + 1 < 1 << (31 - _HALF)
+
+
+def _halves(x) -> jax.Array:
+    """A 32-bit array's low and high 16 bits -> (..., 2) int32."""
+    u = jax.lax.bitcast_convert_type(jnp.asarray(x), jnp.uint32)
+    return jnp.stack([u & 0xFFFF, u >> _HALF], axis=-1).astype(jnp.int32)
+
+
+def _join(key: jax.Array, dtype) -> jax.Array:
+    """The value whose :func:`_halves` two packed keys (..., 2) carry, in
+    ``dtype``; 0 where the keys are ``_NONE``."""
+    h = jnp.where(key >= 0, key & 0xFFFF, 0).astype(jnp.uint32)
+    return jax.lax.bitcast_convert_type(h[..., 0] | h[..., 1] << _HALF, dtype)
+
+
+def _earlier_rw_gathered(trace: CommandTrace, carry: SegmentCarry, is_rw,
+                         rw_in_bank):
+    """:func:`_earlier_rw_packed` by index and gather, for any length."""
+    bank = trace.bank
+    idx = jnp.arange(bank.shape[0], dtype=jnp.int32)
+    prev_rw = _exclusive_cummax(jnp.where(is_rw, idx, _NONE),
+                                _carried(carry.has_prev))             # (N,)
+    has_prev = prev_rw > _NONE
+    prev_rw_c = jnp.maximum(prev_rw, 0)
+    prev_data = jnp.where(
+        (prev_rw >= 0)[:, None], trace.data[prev_rw_c],
+        jnp.where((prev_rw == -1)[:, None], carry.prev_data, 0)
+    ).astype(trace.data.dtype)                                        # (N,16)
+    prev_bank = jnp.where(prev_rw >= 0, bank[prev_rw_c],
+                          jnp.where(prev_rw == -1, carry.prev_bank, -1))
+
+    last_rw_in_bank = _exclusive_cummax(
+        jnp.where(rw_in_bank, idx[:, None], _NONE),
+        _carried(jnp.asarray(carry.last_col) >= 0))
+    this_bank_last = jnp.take_along_axis(last_rw_in_bank, bank[:, None],
+                                         axis=1)[:, 0]                # (N,)
+    prev_col_same_bank = jnp.where(
+        this_bank_last >= 0, trace.col[jnp.maximum(this_bank_last, 0)],
+        jnp.where(this_bank_last == -1,
+                  jnp.asarray(carry.last_col)[bank], -1))
+    return prev_data, has_prev, prev_bank, prev_col_same_bank
+
+
+def _earlier_rw_packed(trace: CommandTrace, carry: SegmentCarry, is_rw,
+                       rw_in_bank):
+    """Per command, from the RD/WRs before it (carry included): the
+    previous one's line (0 if none), whether there is one, its bank, and
+    the column of the last one in this command's bank (-1 if none).
+    Every 32-bit field rides as two packed keys through one exclusive
+    cummax; this command's bank picks its column by a one-hot select.
+    Needs :func:`gather_free` of the length."""
+    bank = trace.bank
+    n = bank.shape[0]
+    n_line = dram.LINE_WORDS + 1                 # the line's words, the bank
+    last_col = jnp.asarray(carry.last_col)
+    # 25 slots (the words, the bank, each bank's column): per command its
+    # event and halves, and the carry's at position 0
+    event = jnp.concatenate(
+        [jnp.broadcast_to(is_rw[:, None], (n, n_line)), rw_in_bank], axis=1)
+    half = jnp.concatenate(
+        [_halves(trace.data), _halves(bank)[:, None],
+         jnp.broadcast_to(_halves(trace.col)[:, None], (n, N_BANKS, 2))],
+        axis=1)                                                   # (N,25,2)
+    carried = jnp.concatenate([jnp.broadcast_to(carry.has_prev, (n_line,)),
+                               last_col >= 0])
+    carried_half = jnp.concatenate([_halves(carry.prev_data),
+                                    _halves(carry.prev_bank)[None],
+                                    _halves(last_col)])
+    # the latest position wins the cummax and brings its half along
+    pos = jnp.arange(1, n + 1, dtype=jnp.int32)[:, None, None] << _HALF
+    key = _exclusive_cummax(
+        jnp.where(event[..., None], pos | half, _NONE),
+        jnp.where(carried[:, None], carried_half, _NONE))          # (N,25,2)
+    has_prev = key[:, 0, 0] >= 0
+    prev_data = _join(key[:, :dram.LINE_WORDS], trace.data.dtype)
+    prev_bank = jnp.where(has_prev, _join(key[:, dram.LINE_WORDS],
+                                          jnp.int32), -1)
+    # the bank as the gather indexes: a negative one counts from the
+    # end, and one past either end reads none
+    at = jax.nn.one_hot(jnp.where(bank < 0, bank + N_BANKS, bank), N_BANKS,
+                        dtype=jnp.bool_)
+    in_bank = jnp.max(jnp.where(at[..., None], key[:, n_line:], _NONE),
+                      axis=1)                                         # (N,2)
+    prev_col_same_bank = jnp.where(in_bank[:, 0] >= 0,
+                                   _join(in_bank, jnp.int32), -1)
+    return prev_data, has_prev, prev_bank, prev_col_same_bank
+
+
 class StructuralState(NamedTuple):
     """The index-bookkeeping half of the structural pass: everything the
     trace alone determines EXCEPT the O(N x 512 bit) data reductions.
@@ -378,7 +477,8 @@ def structural_state(trace: CommandTrace,
     """The index bookkeeping of one trace (or one segment of one, with the
     ``carry`` of the commands before it; None is :func:`empty_carry`).
     Every exclusive cummax starts from the carry: a carried event sits at
-    index -1, before the segment's own."""
+    index -1, before the segment's own.  Where :func:`gather_free` holds
+    for the row length, nothing is gathered."""
     if carry is None:
         carry = empty_carry()
     cmd, bank = trace.cmd, trace.bank
@@ -424,29 +524,11 @@ def structural_state(trace: CommandTrace,
                          jnp.where(in_pdn, pd_kind, BG_ACTIVE)
                          ).astype(jnp.int32)
 
-    # ---- previous RD/WR on the bus (for toggles & interleave mode) --------
-    prev_rw = _exclusive_cummax(jnp.where(is_rw, idx, _NONE),
-                                _carried(carry.has_prev))             # (N,)
-    has_prev = prev_rw > _NONE
-    prev_rw_c = jnp.maximum(prev_rw, 0)
-    prev_data = jnp.where(
-        (prev_rw >= 0)[:, None], trace.data[prev_rw_c],
-        jnp.where((prev_rw == -1)[:, None], carry.prev_data, 0)
-    ).astype(trace.data.dtype)                                        # (N,16)
-    prev_bank = jnp.where(prev_rw >= 0, bank[prev_rw_c],
-                          jnp.where(prev_rw == -1, carry.prev_bank, -1))
-
-    # last RD/WR column per bank, before each command
-    rw_in_bank = is_rw[:, None] & bank_oh                             # (N,8)
-    last_rw_in_bank = _exclusive_cummax(
-        jnp.where(rw_in_bank, idx[:, None], _NONE),
-        _carried(jnp.asarray(carry.last_col) >= 0))
-    this_bank_last = jnp.take_along_axis(last_rw_in_bank, bank[:, None],
-                                         axis=1)[:, 0]                # (N,)
-    prev_col_same_bank = jnp.where(
-        this_bank_last >= 0, trace.col[jnp.maximum(this_bank_last, 0)],
-        jnp.where(this_bank_last == -1,
-                  jnp.asarray(carry.last_col)[bank], -1))
+    # ---- previous RD/WR on the bus and in the bank (toggles, interleave) --
+    earlier_rw = (_earlier_rw_packed if gather_free(n)
+                  else _earlier_rw_gathered)
+    prev_data, has_prev, prev_bank, prev_col_same_bank = earlier_rw(
+        trace, carry, is_rw, is_rw[:, None] & bank_oh)
 
     # the previous RD/WR in this bank is the previous one on the bus when
     # the banks match, so one column comparison serves both branches

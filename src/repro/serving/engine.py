@@ -36,6 +36,7 @@ import math
 import jax
 
 from repro.core import model_api
+from repro.core.energy_model import gather_free
 from repro.core.estimate_batch import TraceBatch
 from repro.core.fleet import pad_rows
 from repro.kernels.common import pad_carry
@@ -75,10 +76,13 @@ class ServingEngine:
         Shards the trace axis when the mesh has >1 device, padding it to
         a multiple of the device count; identical numerics either way.
         The ``engine.dispatch`` span times the program lookup and the
-        enqueue, not the device work."""
+        enqueue, not the device work; its ``gather_free`` is 1 where the
+        bucket's row length reads earlier commands without gathers
+        (``energy_model.gather_free``)."""
         vendors = (tuple(int(v) for v in vendors)
                    if vendors is not None else None)
-        with span("engine.dispatch"):
+        with span("engine.dispatch") as s:
+            s.attrs["gather_free"] = int(gather_free(tb.trace.cmd.shape[1]))
             if self.n_shards == 1:
                 return self._dispatch_fn(vendors, False)(
                     self.resident, tb.trace, tb.weight, tb.carry)

@@ -158,6 +158,26 @@ def test_service_metrics_are_bounded_and_over_wall_time(quick_vampire):
         assert samples.maxlen == RECENT
 
 
+@pytest.mark.parametrize("length_bucket,gather_free", [(16384, 1),
+                                                       (1 << 15, 0)])
+def test_engine_dispatch_counts_gather_free_rows(quick_vampire,
+                                                 length_bucket, gather_free):
+    """``engine.dispatch`` says whether the bucket's row length reads
+    earlier commands without gathers: at the ring's 16384, yes; past
+    ``energy_model.gather_free``'s gate, no."""
+    from repro.serving.ring import RingConfig
+    svc = EstimationService(quick_vampire, ServiceConfig(
+        ring=RingConfig(length_buckets=(256, length_bucket))))
+    trace = idd_loops.validation_sweep(1000)      # 8016 commands
+    t0 = time.perf_counter()
+    (ticket,), _ = svc.submit_many([trace])
+    assert svc.drain() == 1
+    by = {r.name: r for r in _since(t0)}
+    assert by["ring.repad"].attrs["slot_cmds"] == 8 * length_bucket
+    assert by["engine.dispatch"].attrs == {"gather_free": gather_free}
+    svc.result(ticket)
+
+
 def test_service_keeps_only_recent_rejections_but_counts_all(
         quick_vampire, monkeypatch):
     from repro.serving import service
